@@ -14,6 +14,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.base import get_config
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import model as M
 from repro.optim import adam
@@ -36,7 +37,7 @@ step0 = jax.jit(make_train_step(cfg, q_chunk=16, k_chunk=16, loss_chunk=16))
 p0, _, m0 = step0(params, opt, batch)
 
 # 2x2 mesh with CLEAVE rules
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 rules = make_rules(mesh, mode="train")
 with mesh:
     step1 = jax.jit(make_train_step(cfg, rules=rules, q_chunk=16,

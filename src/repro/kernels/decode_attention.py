@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref,
@@ -70,8 +71,9 @@ def _paged_kernel(pt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (page, D)
+    k = k_ref[0, 0].astype(jnp.float32)               # (page, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)  # (G, page)
     tok = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     ok = tok < ln_ref[b]
@@ -82,8 +84,9 @@ def _paged_kernel(pt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(s - m_new) * ok                       # (G, page)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, :, 0, :],
+    pv = jax.lax.dot_general(p, v_ref[0, 0].astype(jnp.float32),
                              (((1,), (0,)), ((), ())),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)  # (G, D)
     acc_ref[...] = acc_ref[...] * corr + pv
     m_ref[...] = m_new
@@ -101,17 +104,21 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     place**, steered by scalar-prefetched per-request page tables — no
     contiguous gather (the TPU twin of ``serving.PagedKVCache.gather``).
 
-    q: (B, K, G, D) grouped queries; k_pool/v_pool: (P, page, K, D) page
-    pools of one layer; page_table: (B, maxp) int32 page ids (entries past
-    a request's allocation point anywhere — masked); lengths: (B,) int32
-    occupied tokens per request.  Returns (B, K, G, D).
+    q: (B, K, G, D) grouped queries; k_pool/v_pool: (P, K, page, D) page
+    pools of one layer, head-major within a page; page_table: (B, maxp)
+    int32 page ids (entries past a request's allocation point anywhere —
+    masked); lengths: (B,) int32 occupied tokens per request.  Returns
+    (B, K, G, D).  Scores and values are contracted in float32.
 
     Grid (B, K, maxp), page axis innermost: the page table is prefetched
     (``PrefetchScalarGridSpec``), so each step's k/v block DMA is indexed
-    ``pool[page_table[b, j]]`` — the kernel walks each request's scattered
-    pages in order while the running (max, denom, acc) live in VMEM."""
+    ``pool[page_table[b, j], kv_head]`` — the kernel walks each request's
+    scattered pages in order while the running (max, denom, acc) live in
+    VMEM.  Head-major pages make each block a whole (page, D) tile, the
+    shape the TPU's block tiling accepts (a (page, 1, D) slice of a
+    token-major page is not)."""
     B, K, G, D = q.shape
-    P, page = k_pool.shape[0], k_pool.shape[1]
+    P, page = k_pool.shape[0], k_pool.shape[2]
     maxp = page_table.shape[1]
     scale = 1.0 / np.sqrt(D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -119,10 +126,10 @@ def flash_decode_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid=(B, K, maxp),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, kh, j, pt, ln: (b, kh, 0, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, kh, j, pt, ln: (pt[b, j], 0, kh, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, kh, j, pt, ln: (pt[b, j], 0, kh, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, kh, j, pt, ln: (pt[b, j], kh, 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, kh, j, pt, ln: (pt[b, j], kh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, kh, j, pt, ln: (b, kh, 0, 0)),

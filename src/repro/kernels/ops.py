@@ -1,6 +1,7 @@
 """jit'd wrappers around the Pallas kernels: shape padding, GQA head
-expansion, backend dispatch (interpret=True on CPU — kernels execute in
-Python for correctness validation; compiled on TPU).
+expansion, backend dispatch (compiled on TPU; interpret=True on CPU, where
+kernels execute in Python for correctness validation; any other backend is
+an error).
 """
 from __future__ import annotations
 
@@ -18,7 +19,16 @@ from repro.kernels import wkv6 as _wkv
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode: off on TPU, on for the CPU test path.  Any
+    other backend has no compiled lowering here and is refused rather than
+    silently interpreted."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run compiled on TPU or interpreted "
+                       f"on CPU; the default backend is {backend!r}")
 
 
 def _pad_to(x, mult, axis):
@@ -55,7 +65,8 @@ class PadCache:
     a runtime step loop calls ``plan_gemm`` once per level GEMM with the
     same operands, so the padded device arrays are cached keyed by
     ``(role, source shape, padded shape)`` plus a full-buffer content
-    fingerprint (adler32 over the raw bytes, ~40% of the staging cost).
+    fingerprint (adler32 over the raw bytes, read as ``uint8`` so that
+    ml_dtypes sources such as bfloat16 hash too; ~40% of the staging cost).
     Content keying makes the cache safe under the common training pattern
     of *in-place* operand updates between steps — a mutated array simply
     fingerprints as a miss instead of serving a stale device copy.
@@ -83,7 +94,7 @@ class PadCache:
         import zlib
         if not src.flags.c_contiguous:
             return None
-        return zlib.adler32(memoryview(src).cast("B"))
+        return zlib.adler32(src.reshape(-1).view(np.uint8))
 
     def get(self, src, key, build):
         fp = self.fingerprint(src)
@@ -448,12 +459,12 @@ def gqa_flash_decode(q, k, v, valid, *, bs=512):
 def gqa_flash_decode_paged(q, k_pool, v_pool, page_table, lengths):
     """Paged-KV single-token GQA decode: attention reads the serving page
     pools in place through per-request page tables (no contiguous gather).
-    q: (B,1,H,D); k_pool/v_pool: (P,page,K,D) — one layer's pools from
-    ``serving.PagedKVCache``; page_table: (B,maxp) int32; lengths: (B,)
-    int32 occupancy.  Returns (B,1,H,D)."""
+    q: (B,1,H,D); k_pool/v_pool: (P,K,page,D) — one layer's head-major
+    pools from ``serving.PagedKVCache``; page_table: (B,maxp) int32;
+    lengths: (B,) int32 occupancy.  Returns (B,1,H,D)."""
     from repro.kernels import decode_attention as _dec
     B, _, H, D = q.shape
-    K = k_pool.shape[2]
+    K = k_pool.shape[1]
     G = H // K
     qf = q.reshape(B, K, G, D)
     out = _dec.flash_decode_paged(qf, k_pool, v_pool, page_table, lengths,

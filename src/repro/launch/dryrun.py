@@ -85,7 +85,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     import jax
     from repro.configs.base import INPUT_SHAPES, get_config
     from repro.launch import steps as ST
-    from repro.launch.mesh import HW, make_production_mesh
+    from repro.launch.mesh import HW, make_mesh, make_production_mesh
     from repro.parallel.sharding import make_rules
 
     cfg = get_config(arch)
@@ -93,7 +93,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     if mesh_override:
         dims = tuple(int(x) for x in mesh_override.split("x"))
         axes = ("pod", "data", "model")[-len(dims):]
-        mesh = jax.make_mesh(dims, axes)
+        mesh = make_mesh(dims, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.size

@@ -8,6 +8,16 @@ import to materialize the placeholder devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes.  JAX makes ``Explicit`` axes by
+    default, and the sharding rules here steer GSPMD through
+    ``with_sharding_constraint``, which only ``Auto`` axes accept."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,15 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = ('pod', 'data', 'model'), 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(n_data: int = 2, n_model: int = 2, *, pod: int = 0):
-    """Small mesh over however many (host) devices exist — used by tests."""
-    if pod:
-        return jax.make_mesh((pod, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 HW = {

@@ -19,6 +19,70 @@ import argparse
 import time
 
 
+def decode(cfg, params, prompts, gen: int, *, kv_int8: bool = False,
+           temperature: float = 0.0, key=None, encoder_feats=None):
+    """Monolithic decode: prefill ``prompts`` (B, P) in one pass, then
+    ``gen`` jitted decode steps against the KV cache.  Returns
+    ``(tokens, t_prefill, t_token)``: the (B, gen) generated ids, the
+    prefill wall time and the mean wall time per decode step, in seconds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.models import model as M
+
+    if key is None:
+        key = jax.random.PRNGKey(0)
+    B, P = prompts.shape
+    batch = {"tokens": prompts}
+    if cfg.enc_dec:
+        batch["encoder_feats"] = encoder_feats
+    t0 = time.perf_counter()
+    logits, pre_cache = M.prefill(cfg, params, batch)
+    t_prefill = time.perf_counter() - t0
+
+    cache = M.init_cache(cfg, B, P + gen,
+                         enc_len=(2 * P if cfg.enc_dec else 0),
+                         kv_quant=kv_int8)
+    for nm in ("k", "v", "ckv", "kpe"):
+        if nm in cache and nm in pre_cache and not kv_int8:
+            cache[nm] = cache[nm].at[:, :, :P].set(
+                pre_cache[nm].astype(cache[nm].dtype))
+    for nm in ("wkv_state", "tm_prev", "cm_prev"):
+        if nm in pre_cache:
+            cache[nm] = pre_cache[nm]
+    if cfg.enc_dec:
+        from repro.models import encdec
+        ck, cv = encdec.prepare_cross_cache(cfg, params, encoder_feats)
+        cache["cross_k"], cache["cross_v"] = ck, cv
+    if kv_int8:
+        # re-ingest the prompt token by token (quantized writes)
+        cache["pos"] = jnp.zeros((), jnp.int32)
+        step_fn = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
+        for t in range(P):
+            logits, cache = step_fn(params, cache, prompts[:, t:t + 1])
+    else:
+        cache["pos"] = pre_cache["pos"]
+
+    step_fn = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
+
+    def sample(lg, k):
+        lg = lg[:, -1, :cfg.vocab_size]
+        if temperature <= 0:
+            return jnp.argmax(lg, axis=-1)[:, None]
+        return jax.random.categorical(k, lg / temperature)[:, None]
+
+    tok = sample(logits, key)
+    out = [np.asarray(tok)]
+    t0 = time.perf_counter()
+    for i in range(gen - 1):
+        key, sk = jax.random.split(key)
+        logits, cache = step_fn(params, cache, tok.astype(jnp.int32))
+        tok = sample(logits, sk)
+        out.append(np.asarray(tok))
+    t_token = (time.perf_counter() - t0) / max(gen - 1, 1)
+    return np.concatenate(out, axis=1), t_prefill, t_token
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
@@ -39,10 +103,14 @@ def main(argv=None):
                          "engine-priced latency as the predicted column")
     ap.add_argument("--page-size", type=int, default=16,
                     help="edge path: tokens per KV page")
+    ap.add_argument("--fleet-exec", default="numpy",
+                    choices=("numpy", "jax"),
+                    help="edge path: fleet executor substrate (numpy: "
+                         "float64 host stand-in; jax: Pallas/XLA batched "
+                         "kernels on the default device)")
     args = ap.parse_args(argv)
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from repro.configs.base import get_config
     from repro.models import model as M
@@ -54,57 +122,12 @@ def main(argv=None):
     params = M.init_params(cfg, key)
     B, P, G = args.batch, args.prompt_len, args.gen
     prompts = jax.random.randint(key, (B, P), 0, cfg.vocab_size)
-
-    batch = {"tokens": prompts}
-    if cfg.enc_dec:
-        batch["encoder_feats"] = jax.random.normal(
-            key, (B, 2 * P, cfg.d_model))
-    t0 = time.perf_counter()
-    logits, pre_cache = M.prefill(cfg, params, batch)
-    t_prefill = time.perf_counter() - t0
-
-    cache = M.init_cache(cfg, B, P + G,
-                         enc_len=(2 * P if cfg.enc_dec else 0),
-                         kv_quant=args.kv_int8)
-    for nm in ("k", "v", "ckv", "kpe"):
-        if nm in cache and nm in pre_cache and not args.kv_int8:
-            cache[nm] = cache[nm].at[:, :, :P].set(
-                pre_cache[nm].astype(cache[nm].dtype))
-    for nm in ("wkv_state", "tm_prev", "cm_prev"):
-        if nm in pre_cache:
-            cache[nm] = pre_cache[nm]
-    if cfg.enc_dec:
-        from repro.models import encdec
-        ck, cv = encdec.prepare_cross_cache(cfg, params,
-                                            batch["encoder_feats"])
-        cache["cross_k"], cache["cross_v"] = ck, cv
-    if args.kv_int8:
-        # re-ingest the prompt token by token (quantized writes)
-        cache["pos"] = jnp.zeros((), jnp.int32)
-        step_fn = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
-        for t in range(P):
-            logits, cache = step_fn(params, cache, prompts[:, t:t + 1])
-    else:
-        cache["pos"] = pre_cache["pos"]
-
-    step_fn = jax.jit(lambda p, c, t: M.decode_step(cfg, p, c, t))
-
-    def sample(lg, k):
-        lg = lg[:, -1, :cfg.vocab_size]
-        if args.temperature <= 0:
-            return jnp.argmax(lg, axis=-1)[:, None]
-        return jax.random.categorical(k, lg / args.temperature)[:, None]
-
-    tok = sample(logits, key)
-    out = [np.asarray(tok)]
-    t0 = time.perf_counter()
-    for i in range(G - 1):
-        key, sk = jax.random.split(key)
-        logits, cache = step_fn(params, cache, tok.astype(jnp.int32))
-        tok = sample(logits, sk)
-        out.append(np.asarray(tok))
-    dt = (time.perf_counter() - t0) / max(G - 1, 1)
-    gen = np.concatenate(out, axis=1)
+    feats = (jax.random.normal(key, (B, 2 * P, cfg.d_model))
+             if cfg.enc_dec else None)
+    gen, t_prefill, dt = decode(cfg, params, prompts, G,
+                                kv_int8=args.kv_int8,
+                                temperature=args.temperature, key=key,
+                                encoder_feats=feats)
     print(f"arch={cfg.name} prefill={t_prefill * 1000:.0f}ms "
           f"decode={dt * 1000:.1f}ms/tok kv_int8={args.kv_int8}")
     for b in range(min(B, 2)):
@@ -128,7 +151,7 @@ def main(argv=None):
         sess = rt.serve_session(params, slots=B,
                                 page_size=args.page_size,
                                 max_len=P + G, kv_int8=args.kv_int8,
-                                seed=args.seed)
+                                backend=args.fleet_exec, seed=args.seed)
         pn = np.asarray(prompts)
         for b in range(B):
             sess.submit(pn[b], max_new=G)
@@ -150,4 +173,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -29,7 +29,9 @@ import time
 import numpy as np
 
 
-def main(argv=None):
+def run(argv=None):
+    """Parse ``argv`` and train.  Returns ``(params, history)``: the final
+    parameters and one metrics row per step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--reduced", action="store_true",
@@ -117,8 +119,9 @@ def main(argv=None):
         if args.backend == "fleet":
             raise SystemExit("--mesh and --backend fleet are exclusive: "
                              "the fleet IS the device layer")
+        from repro.launch.mesh import make_mesh
         dims = tuple(int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh(dims, ("data", "model")[-len(dims):])
+        mesh = make_mesh(dims, ("data", "model")[-len(dims):])
         rules = make_rules(mesh, mode="train")
 
     opt_cfg = adam.AdamConfig(lr=args.lr, warmup_steps=min(20, args.steps),
@@ -126,6 +129,18 @@ def main(argv=None):
     key = jax.random.PRNGKey(args.seed)
     params = M.init_params(cfg, key)
     opt_state = adam.init(params, opt_cfg)
+    out_shardings = None
+    if rules is not None:
+        # place params and moments with the CLEAVE 2-D shardings, and keep
+        # them there across steps (donated buffers must alias)
+        from repro.launch import specs as SP
+        p_specs = SP.param_specs(cfg, rules)
+        psh = jax.tree.map(lambda s: s.sharding, p_specs)
+        osh = jax.tree.map(lambda s: s.sharding,
+                           SP.opt_specs(p_specs, rules))
+        params = jax.device_put(params, psh)
+        opt_state = jax.device_put(opt_state, osh)
+        out_shardings = (psh, osh, None)
     n_params = sum(x.size for x in jax.tree.leaves(params))
     print(f"arch={cfg.name} params={n_params:,} vocab={cfg.vocab_size} "
           f"layers={cfg.n_layers} d={cfg.d_model}")
@@ -163,7 +178,7 @@ def main(argv=None):
         step_fn = jax.jit(make_train_step(cfg, opt_cfg, rules=rules,
                                           q_chunk=64, k_chunk=64,
                                           loss_chunk=64),
-                          donate_argnums=(0, 1))
+                          donate_argnums=(0, 1), out_shardings=out_shardings)
 
     mgr = None
     if args.ckpt_dir:
@@ -173,6 +188,7 @@ def main(argv=None):
     history = []
     t0 = time.perf_counter()
     for step in range(args.steps):
+        t_step = time.perf_counter()
         batch = {k: jax.numpy.asarray(v)
                  for k, v in data.batch(step).items()}
         if cfg.modality == "vision":
@@ -196,7 +212,8 @@ def main(argv=None):
         loss = float(metrics["loss"])
         row = {"step": step, "loss": loss,
                "grad_norm": float(metrics["grad_norm"]),
-               "lr": float(metrics["lr"])}
+               "lr": float(metrics["lr"]),
+               "step_time": time.perf_counter() - t_step}
         if fleet_session is not None:
             rep = metrics["fleet"]
             row.update(fleet_gemms=rep.n_gemms, fleet_tasks=rep.n_tasks,
@@ -226,8 +243,15 @@ def main(argv=None):
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
             json.dump(history, f)
+    return params, history
+
+
+def main(argv=None):
+    run(argv)
     return 0
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

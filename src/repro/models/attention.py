@@ -170,7 +170,6 @@ def _decode_attention_sharded(q, k_cache, v_cache, valid, mesh, batch_axes):
     """Explicit flash-decode under shard_map: each model shard scores its
     cache-sequence slice (fused multiply-reduce), then pmax/psum combine."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     B, _, H, Dk = q.shape
     K = k_cache.shape[2]
@@ -194,14 +193,14 @@ def _decode_attention_sharded(q, k_cache, v_cache, valid, mesh, batch_axes):
         o = jax.lax.psum(o, "model")
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(vb.dtype)
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_axes, None, None, None),
                   P(batch_axes, "model", None, None),
                   P(batch_axes, "model", None, None),
                   P("model")),
         out_specs=P(batch_axes, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, valid)
     return out.reshape(B, 1, H, Dv)
 

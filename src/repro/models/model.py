@@ -160,8 +160,8 @@ def forward(cfg, params, batch, *, window=0, q_chunk=256, k_chunk=512,
 
     ``scan_layers=False`` unrolls the layer loop in Python (per-layer param
     slices, no ``lax.scan``, no remat) — the PS-centric fleet training path
-    uses it so fleet-GEMM host callbacks never sit inside compiled control
-    flow.  The unrolled path computes the same values as the scan; it does
+    uses it so fleet GEMMs, which execute on concrete operands, never
+    sit inside compiled control flow.  The unrolled path computes the same values as the scan; it does
     not collect KV (training/loss never reads it)."""
     x, positions = fuse_inputs(cfg, params, batch)
 
@@ -344,8 +344,8 @@ def decode_step(cfg, params, cache, tokens, *, window=0, scan_layers=True):
 
     ``scan_layers=False`` unrolls the layer loop in Python (per-layer param
     slices, no ``lax.scan``) — the fleet serving path uses it so the
-    ``pdot``/``fleet_dot`` host callbacks never sit inside compiled control
-    flow; same values as the scan."""
+    ``pdot``/``fleet_dot`` GEMMs, which execute on concrete operands,
+    never sit inside compiled control flow; same values as the scan."""
     B = tokens.shape[0]
     x = L.embed_tokens(params["embed"], tokens, cfg)
     pos = cache["pos"]

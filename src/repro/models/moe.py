@@ -120,7 +120,6 @@ def _moe_block_sharded(cfg, p, x, rules):
     """shard_map expert-parallel MoE: tokens stay on their ('pod','data')
     shards, experts are partitioned over 'model'."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = rules.mesh
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -189,12 +188,12 @@ def _moe_block_sharded(cfg, p, x, rules):
         return (out.astype(x.dtype).reshape(x_blk.shape), aux)
 
     x_spec = P(batch_axes, None, "model" if d_shard else None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.n_shared_experts:

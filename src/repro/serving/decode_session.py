@@ -307,6 +307,7 @@ class ServeSession:
         pools **in place** (page-table scalar prefetch) must match dense
         attention over the gathered contiguous view — the TPU read path vs
         the PS read path, same pages."""
+        import jax
         import jax.numpy as jnp
 
         from repro.kernels import ops
@@ -339,8 +340,11 @@ class ServeSession:
             v = v.astype(jnp.float32) \
                 * jnp.asarray(views["v_scale"][0])[..., None]
         valid = jnp.arange(self.cache_len)[None, :] < jnp.asarray(ln)[:, None]
-        # rows with ln == 0 are fully masked in the oracle; skip them
-        want = decode_attention(self._check_q, k, v, valid)
+        # rows with ln == 0 are fully masked in the oracle; skip them.  The
+        # kernel contracts in float32, so the oracle must too (the TPU's
+        # default float32 matmul rounds its operands to bfloat16)
+        with jax.default_matmul_precision("highest"):
+            want = decode_attention(self._check_q, k, v, valid)
         live = np.asarray(ln) > 0
         np.testing.assert_allclose(np.asarray(got)[live],
                                    np.asarray(want)[live],

@@ -3,8 +3,11 @@
 The parameter server owns one pool of fixed-size pages per cached tensor
 (K/V for GQA families, compressed c_kv/k_pe for MLA), stacked over layers:
 
-    k pool: (L, n_pages, page, K, hd)      v pool: same
+    k pool: (L, n_pages, K, page, hd)      v pool: same
     ckv pool: (L, n_pages, page, r)        kpe pool: (L, n_pages, page, rd)
+
+K/V pages are head-major: one layer's page of one KV head is a whole
+(page, hd) tile, the block the Pallas paged-decode kernel reads in place.
 
 Each live request holds a page table — an ordered list of page ids — and a
 token count.  Pages are reserved **at admission** for the request's whole
@@ -30,6 +33,9 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+
+# pools whose pages are head-major: (L, n_pages, K, page, ...)
+HEAD_MAJOR = ("k", "v", "k_scale", "v_scale")
 
 
 def quantize_kv(x: np.ndarray):
@@ -94,13 +100,14 @@ class PagedKVCache:
         else:
             K, hd = cfg.n_kv_heads, cfg.head_dim
             kv_dt = np.int8 if kv_int8 else dtype
+            shp = (L, self.n_pages, K, self.page)
             self.pools = {
-                "k": np.zeros(shp + (K, hd), kv_dt),
-                "v": np.zeros(shp + (K, hd), kv_dt),
+                "k": np.zeros(shp + (hd,), kv_dt),
+                "v": np.zeros(shp + (hd,), kv_dt),
             }
             if kv_int8:
-                self.pools["k_scale"] = np.zeros(shp + (K,), np.float16)
-                self.pools["v_scale"] = np.zeros(shp + (K,), np.float16)
+                self.pools["k_scale"] = np.zeros(shp, np.float16)
+                self.pools["v_scale"] = np.zeros(shp, np.float16)
         self._free: List[int] = list(range(self.n_pages - 1, -1, -1))
         self.tables: Dict[int, PageTable] = {}
         self.peak_pages_used = 0
@@ -144,13 +151,31 @@ class PagedKVCache:
 
     # --------------------------------------------------------------- writes --
 
-    def _flat(self, rid: int, pos) -> np.ndarray:
-        """Flat pool row index (page_id * page + offset) for absolute
-        position(s) ``pos`` of request ``rid``."""
-        pt = self.tables[rid]
+    def _loc(self, rid: int, pos):
+        """(page id, offset in page) of absolute position(s) ``pos`` of
+        request ``rid``."""
         pos = np.asarray(pos)
-        pages = np.asarray(pt.pages, np.int64)
-        return pages[pos // self.page] * self.page + pos % self.page
+        pages = np.asarray(self.tables[rid].pages, np.int64)
+        return pages[pos // self.page], pos % self.page
+
+    @staticmethod
+    def _put(nm: str, pool: np.ndarray, pid, off, val) -> None:
+        """Write token rows ``val`` (L, *idx, ...) at pages ``pid`` and
+        offsets ``off``."""
+        if nm in HEAD_MAJOR:
+            # the page-id and offset indices sit either side of the head
+            # slice, so numpy puts their dims first: (*idx, L, K, ...)
+            pool[:, pid, :, off] = np.moveaxis(val, 1, 0)
+        else:
+            pool[:, pid, off] = val
+
+    @staticmethod
+    def _take(nm: str, pool: np.ndarray, pid, off) -> np.ndarray:
+        """Token rows at pages ``pid`` and offsets ``off`` (both (B, S)),
+        as (L, B, S, ...) with the head axis after the token axis."""
+        if nm in HEAD_MAJOR:
+            return np.moveaxis(pool[:, pid, :, off], 2, 0)
+        return pool[:, pid, off]
 
     def write_prompt(self, rid: int, values: Dict[str, np.ndarray]) -> None:
         """Ingest a prefilled prompt: ``values[name]`` is (L, P, ...) —
@@ -161,11 +186,10 @@ class PagedKVCache:
             for nm in ("k", "v"):
                 values[nm], values[nm + "_scale"] = quantize_kv(values[nm])
         P = next(iter(values.values())).shape[1]
-        idx = self._flat(rid, np.arange(P))
+        pid, off = self._loc(rid, np.arange(P))
         for nm, val in values.items():
             pool = self.pools[nm]
-            flat = pool.reshape((pool.shape[0], -1) + pool.shape[3:])
-            flat[:, idx] = val.astype(pool.dtype, copy=False)
+            self._put(nm, pool, pid, off, val.astype(pool.dtype, copy=False))
         self.tables[rid].length = max(self.tables[rid].length, P)
 
     def write_tokens(self, rids: Sequence[int], pos: Sequence[int],
@@ -175,11 +199,12 @@ class PagedKVCache:
         step quantizes in-model, exactly like the monolithic path)."""
         if not len(rids):
             return
-        idx = np.stack([self._flat(r, p) for r, p in zip(rids, pos)])
+        locs = [self._loc(r, p) for r, p in zip(rids, pos)]
+        pid = np.stack([pg for pg, _ in locs])
+        off = np.stack([o for _, o in locs])
         for nm, val in values.items():
             pool = self.pools[nm]
-            flat = pool.reshape((pool.shape[0], -1) + pool.shape[3:])
-            flat[:, idx] = val.astype(pool.dtype, copy=False)
+            self._put(nm, pool, pid, off, val.astype(pool.dtype, copy=False))
         for r, p in zip(rids, pos):
             self.tables[r].length = max(self.tables[r].length, int(p) + 1)
 
@@ -191,7 +216,8 @@ class PagedKVCache:
         one vectorized fancy-index per pool.  ``rids`` may contain ``None``
         (inactive batch slots → rows of page 0, hidden by the occupancy
         mask)."""
-        idx = np.zeros((len(rids), cache_len), np.int64)
+        pid = np.zeros((len(rids), cache_len), np.int64)
+        off = np.zeros((len(rids), cache_len), np.int64)
         offs = np.arange(cache_len)
         for b, rid in enumerate(rids):
             if rid is None:
@@ -199,12 +225,10 @@ class PagedKVCache:
             pt = self.tables[rid]
             cap = len(pt.pages) * self.page
             n = min(cache_len, cap)
-            idx[b, :n] = self._flat(rid, offs[:n])
-        out = {}
-        for nm, pool in self.pools.items():
-            flat = pool.reshape((pool.shape[0], -1) + pool.shape[3:])
-            out[nm] = flat[:, idx]          # (L, B, cache_len, ...)
-        return out
+            pid[b, :n], off[b, :n] = self._loc(rid, offs[:n])
+        # (L, B, cache_len, ...)
+        return {nm: self._take(nm, pool, pid, off)
+                for nm, pool in self.pools.items()}
 
     def page_table_array(self, rids: Sequence[Optional[int]]
                          ) -> "tuple[np.ndarray, np.ndarray]":

@@ -9,7 +9,7 @@ Layout
 ``hook``        the pluggable GEMM hook that ``models.layers.pdot`` consults
                 (dependency-free; safe to import from model code).
 ``fleet_gemm``  :class:`FleetGemmSession` — a differentiable ``fleet_dot``
-                (``jax.custom_vjp`` + ``pure_callback``) that runs each
+                (``jax.custom_vjp`` over concrete operands) that runs each
                 intercepted GEMM, and its two backward mirrors
                 (dA = dO·Bᵀ, dW = Aᵀ·dO), through the session runtime's
                 numpy/jax fleet executors.

@@ -14,13 +14,16 @@ plan cache, the failure/recovery path (``churn.recover``), Freivalds
 verification, and — for ``backend="jax"`` — the Pallas/XLA batched kernels
 with the session ``PadCache``.
 
-Sessions are process-global and non-nested (the callback inside a
-``pure_callback`` cannot thread ``self`` through JAX), opened via
+Sessions are process-global and non-nested (the one ``custom_vjp``
+primitive is shared by every caller and cannot thread ``self``), opened via
 :meth:`FleetGemmSession.open`, which also installs the ``models.layers.pdot``
-hook.  The fleet step must run **eagerly** (no outer ``jax.jit``): the
-model's unrolled path (``forward(..., scan_layers=False)``) keeps callbacks
-out of compiled scans, so a jax-executor backend never re-enters XLA from
-inside a running computation.
+hook.  The fleet step must run **eagerly** (no outer ``jax.jit``): under
+eager autodiff the primal and both cotangent rules see concrete arrays, so
+the host executes each GEMM directly, between device programs.  The
+model's unrolled path (``forward(..., scan_layers=False)``) keeps the GEMMs
+out of compiled scans.  A host callback would not do: JAX runs a
+``pure_callback`` body with the CPU as default device, so a jax executor
+inside it would stage its operands off the accelerator.
 """
 from __future__ import annotations
 
@@ -272,20 +275,20 @@ class FleetGemmSession:
 def _host_gemm(kind: str, a, b) -> np.ndarray:
     sess = _SESSION
     if sess is None:
-        # hook installed without an open session (shouldn't happen through
-        # FleetGemmSession.open); degrade to the monolithic product
-        return np.asarray(a) @ np.asarray(b)
+        raise RuntimeError("fleet GEMM outside an open FleetGemmSession: "
+                           "open one with FleetGemmSession.open()")
     return sess._execute(np.asarray(a), np.asarray(b), kind)
 
 
 def _raw_fleet_dot(a, b, kind: str):
-    import functools
-
     import jax
+    import jax.numpy as jnp
 
-    out_sd = jax.ShapeDtypeStruct((a.shape[0], b.shape[1]), a.dtype)
-    return jax.pure_callback(functools.partial(_host_gemm, kind),
-                             out_sd, a, b)
+    if isinstance(a, jax.core.Tracer) or isinstance(b, jax.core.Tracer):
+        raise TypeError(
+            "fleet GEMMs execute on concrete operands: run the fleet step "
+            "eagerly, with no jax.jit, vmap or scan around it")
+    return jnp.asarray(_host_gemm(kind, a, b))
 
 
 def _make_fleet_dot():

@@ -6,6 +6,7 @@ the accelerator substrate).  All jax paths run on CPU via interpret=True
 (``kernel="pallas"``) or compiled XLA (``kernel="xla"``)."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -348,6 +349,28 @@ def test_pad_cache_reuses_device_operands(rng):
                          compute_dtype="float32", pad_cache=pc)[0]
     _assert_close(blk2, _oracle(A2, B)[0:100, 0:60])
     assert pc.misses == 3
+
+
+def test_pad_cache_fingerprints_bfloat16(rng):
+    """bfloat16 (an ml_dtypes type) has no buffer-protocol format code; the
+    fingerprint reads its bytes as uint8, and still sees a changed entry."""
+    A = rng.standard_normal((64, 48)).astype(jnp.bfloat16)
+    fp = ops.PadCache.fingerprint(A)
+    assert fp == ops.PadCache.fingerprint(A.copy())
+    A[3, 5] += 1
+    assert ops.PadCache.fingerprint(A) != fp
+    assert ops.PadCache.fingerprint(A.T) is None     # non-contiguous
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Pallas runs compiled on TPU and interpreted on CPU; any other
+    backend is refused instead of silently interpreted."""
+    assert ops._interpret() is True                  # the CPU test path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()
 
 
 def test_corruption_lands_when_verification_disabled(rng):

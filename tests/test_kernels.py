@@ -134,15 +134,19 @@ def test_flash_decode_paged_kernel(page, H, K, D, rng):
     perm = rng.permutation(n_pages)
     pt = np.asarray([perm[:3], perm[3:6], perm[6:9]], np.int32)
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((n_pages, page, K, D)),
+    k_pool = jnp.asarray(rng.standard_normal((n_pages, K, page, D)),
                          jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((n_pages, page, K, D)),
+    v_pool = jnp.asarray(rng.standard_normal((n_pages, K, page, D)),
                          jnp.float32)
     out = ops.gqa_flash_decode_paged(q, k_pool, v_pool, pt, lengths)
-    # oracle: gather each request's pages into a contiguous view
+    # oracle: gather each request's head-major pages into a contiguous view
     S = page * maxp
-    kc = jnp.stack([k_pool[pt[b]].reshape(S, K, D) for b in range(B)])
-    vc = jnp.stack([v_pool[pt[b]].reshape(S, K, D) for b in range(B)])
+
+    def contiguous(pool, b):
+        return pool[pt[b]].transpose(0, 2, 1, 3).reshape(S, K, D)
+
+    kc = jnp.stack([contiguous(k_pool, b) for b in range(B)])
+    vc = jnp.stack([contiguous(v_pool, b) for b in range(B)])
     for b in range(B):
         valid = jnp.arange(S) < lengths[b]
         want = decode_attention(q[b:b + 1], kc[b:b + 1], vc[b:b + 1], valid)

@@ -145,6 +145,60 @@ def test_hooks_do_not_nest():
     assert hook.active() is None
 
 
+def test_fleet_gemm_needs_an_open_session():
+    """No path computes a fleet GEMM without a session: the host side of
+    the fleet dot raises instead of falling back to a plain product."""
+    from repro.train_loop import fleet_gemm
+    a = np.ones((4, 8), np.float32)
+    b = np.ones((8, 3), np.float32)
+    with pytest.raises(RuntimeError, match="FleetGemmSession"):
+        fleet_gemm._host_gemm("fwd", a, b)
+
+
+def test_fleet_gemm_refuses_traced_operands():
+    """Fleet GEMMs run eagerly on concrete operands; a jit around the
+    hooked step is an error, not a host callback."""
+    _, opt_cfg, params, _, _, rt = _setup()
+    from repro.models import layers as L
+    from repro.train_loop.fleet_gemm import FleetGemmSession
+    x = jnp.ones((4, 8), jnp.float32)
+    w = jnp.ones((8, 3), jnp.float32)
+    with FleetGemmSession(rt).open():
+        np.testing.assert_allclose(np.asarray(L.pdot(x, w)), 8.0)
+        with pytest.raises(TypeError, match="eagerly"):
+            jax.jit(L.pdot)(x, w)
+
+
+def test_bf16_fleet_jax_backend_matches_numpy_executor():
+    """bf16 params at small widths ``reduced()`` would force to float32:
+    two fleet steps on the jax executor, whose PadCache fingerprints the
+    bf16 operands, reach the numpy executor's losses.  Both executors
+    round every GEMM output to bf16; they differ only in accumulating in
+    float32 vs float64 before that rounding, so the losses agree to well
+    inside one bf16 unit roundoff (2^-8)."""
+    cfg = dataclasses.replace(get_config("opt-1.3b"), n_layers=2,
+                              d_model=128, n_heads=4, n_kv_heads=4,
+                              d_ff=344, vocab_size=512)
+    assert cfg.dtype == cfg.param_dtype == "bfloat16"
+    opt_cfg = adam.AdamConfig(lr=3e-4, warmup_steps=1, total_steps=4)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=S,
+                                  global_batch=B, seed=0))
+    losses = {}
+    for backend in ("numpy", "jax"):
+        rt = CleaveRuntime(arch=cfg, fleet=Fleet.sample(8, seed=0))
+        sess = rt.train_session(opt_cfg, backend=backend, **CHUNKS)
+        p, o = params, adam.init(params, opt_cfg)
+        losses[backend] = []
+        for step in range(2):
+            p, o, met = sess.step(p, o, _batch(data, step))
+            assert met["fleet"].verified and met["fleet"].n_gemms > 0
+            assert p["embed"]["tok"].dtype == jnp.bfloat16
+            losses[backend].append(float(met["loss"]))
+    assert np.all(np.isfinite(losses["jax"]))
+    np.testing.assert_allclose(losses["jax"], losses["numpy"], rtol=2e-3)
+
+
 def test_unrolled_forward_matches_scan():
     cfg, _, params, _, data, _ = _setup()
     batch = _batch(data, 0)
