@@ -43,6 +43,7 @@ from repro.core import churn, cost_model as cm, executor
 from repro.core.gemm_dag import GemmDag, build_dag
 from repro.core.scheduler import (SchedulePlan, plan_shape_key,
                                   reprice_plan, schedule, solve_level_gemm)
+from repro.core.spans import span
 from repro.api.accounting import (AccountingResult, AccountingStrategy,
                                   get_accounting)
 from repro.api.fleet import Fleet
@@ -102,7 +103,11 @@ class StepReport:
     plan_cached: bool
     backend: str = "numpy"      # 'numpy' | 'jax'
     kernel: str = ""            # jax backend: resolved 'pallas' | 'xla'
-    gflops: float = 0.0         # jax backend: achieved kernel GFLOP/s
+    # host seconds per span (``core.spans`` short names): ``plan`` here,
+    # then the executor's (``ExecutionReport.phases``)
+    phases: Dict[str, float] = field(default_factory=dict)
+    padded_flops: float = 0.0   # jax backend: GEMM FLOPs launched, padding
+    #                             included (``JaxExecutionReport``)
 
 
 @dataclass
@@ -304,12 +309,15 @@ class CleaveRuntime:
         ``dtype_policy`` / ``kernel`` pass through to the jax backend."""
         if gemm is None:
             gemm = cm.GEMM(m=A.shape[0], n=A.shape[1], q=B.shape[1])
-        plan, cached = self._solve_gemm(gemm)
+        phases: Dict[str, float] = {}
+        with span("cleave.fleet.plan", phases):
+            plan, cached = self._solve_gemm(gemm)
         report = self._execute_one(gemm, plan, cached, A, B,
                                    fail_ids=fail_ids,
                                    corrupt_ids=corrupt_ids, verify=verify,
                                    backend=backend,
-                                   dtype_policy=dtype_policy, kernel=kernel)
+                                   dtype_policy=dtype_policy, kernel=kernel,
+                                   phases=phases)
         self.history.append({
             "event": "execute_step", "shape": (gemm.m, gemm.n, gemm.q),
             "backend": report.backend,
@@ -321,7 +329,9 @@ class CleaveRuntime:
                      A: np.ndarray, B: np.ndarray, *,
                      fail_ids: Sequence[int], corrupt_ids: Sequence[int],
                      verify: bool, backend: str, dtype_policy,
-                     kernel: str) -> StepReport:
+                     kernel: str,
+                     phases: Optional[Dict[str, float]] = None
+                     ) -> StepReport:
         t0 = time.perf_counter()
         if backend == "numpy":
             rep = executor.execute_plan(gemm, plan, A, B,
@@ -329,7 +339,7 @@ class CleaveRuntime:
                                         fail_ids=fail_ids,
                                         corrupt_ids=corrupt_ids,
                                         rng=self.rng, verify=verify)
-            kern, gflops = "", 0.0
+            kern, padded = "", 0.0
         elif backend == "jax":
             from repro.core import jax_executor
             if self._pad_cache is None:
@@ -340,16 +350,19 @@ class CleaveRuntime:
                 corrupt_ids=corrupt_ids, rng=self.rng, verify=verify,
                 policy=dtype_policy, kernel=kernel,
                 pad_cache=self._pad_cache)
-            kern, gflops = rep.kernel, rep.gflops
+            kern, padded = rep.kernel, rep.padded_flops
         else:
             raise ValueError(f"unknown executor backend {backend!r}; "
                              "expected 'numpy' or 'jax'")
+        exec_time = time.perf_counter() - t0
+        phases = {} if phases is None else phases
+        phases.update(rep.phases)
         return StepReport(
             gemm=gemm, plan=plan, output=rep.output, verified=rep.verified,
             n_tasks=rep.n_tasks, n_recovered=rep.n_recovered,
-            recovery=rep.recovery, exec_time=time.perf_counter() - t0,
+            recovery=rep.recovery, exec_time=exec_time,
             plan_cached=cached, backend=backend, kernel=kern,
-            gflops=gflops)
+            phases=phases, padded_flops=padded)
 
     def execute_step_deferred(self, A: np.ndarray, B: np.ndarray, *,
                               gemm: Optional[cm.GEMM] = None,
@@ -375,12 +388,14 @@ class CleaveRuntime:
         session stream (default: a child split off ``self.rng``)."""
         if gemm is None:
             gemm = cm.GEMM(m=A.shape[0], n=A.shape[1], q=B.shape[1])
-        plan, cached = self._solve_gemm(gemm)
+        phases: Dict[str, float] = {}
+        with span("cleave.fleet.plan", phases):
+            plan, cached = self._solve_gemm(gemm)
         step, fin = self._execute_one_deferred(
             gemm, plan, cached, A, B, fail_ids=fail_ids,
             corrupt_ids=corrupt_ids, verify=verify, backend=backend,
             dtype_policy=dtype_policy, kernel=kernel, rng=rng,
-            staged=staged)
+            staged=staged, phases=phases)
         self.history.append({
             "event": "execute_step", "shape": (gemm.m, gemm.n, gemm.q),
             "backend": step.backend, "deferred": True,
@@ -394,11 +409,13 @@ class CleaveRuntime:
                               corrupt_ids: Sequence[int], verify: bool,
                               backend: str, dtype_policy, kernel: str,
                               rng: Optional[np.random.Generator] = None,
-                              staged=None):
+                              staged=None,
+                              phases: Optional[Dict[str, float]] = None):
         """Split-phase :meth:`_execute_one`.  The returned StepReport's
-        ``exec_time`` covers the compute phase only; ``finalize()``
-        (thread-safe against other nodes' compute) syncs the verification
-        outcome back into the report and returns the corrected rects."""
+        ``exec_time`` and ``phases`` cover the compute phase only;
+        ``finalize()`` (thread-safe against other nodes' compute) syncs the
+        verification outcome back into the report and returns the
+        corrected rects."""
         if rng is None:
             # never hand the session generator to overlapped verification:
             # a finalize racing the next node's draw would break seeded
@@ -410,7 +427,7 @@ class CleaveRuntime:
                 gemm, plan, A, B, self.fleet.devices, fail_ids=fail_ids,
                 corrupt_ids=corrupt_ids, rng=rng, verify=verify,
                 staged=staged)
-            kern, gflops = "", 0.0
+            kern, padded = "", 0.0
         elif backend == "jax":
             from repro.core import jax_executor
             if self._pad_cache is None:
@@ -421,16 +438,21 @@ class CleaveRuntime:
                 corrupt_ids=corrupt_ids, rng=rng, verify=verify,
                 policy=dtype_policy, kernel=kernel,
                 pad_cache=self._pad_cache)
-            kern, gflops = rep.kernel, rep.gflops
+            kern, padded = rep.kernel, rep.padded_flops
         else:
             raise ValueError(f"unknown executor backend {backend!r}; "
                              "expected 'numpy' or 'jax'")
+        exec_time = time.perf_counter() - t0
+        # a copy: the deferred finalize adds its verify phase to the
+        # executor's own dict, on whichever thread runs it
+        phases = {} if phases is None else phases
+        phases.update(rep.phases)
         step = StepReport(
             gemm=gemm, plan=plan, output=rep.output, verified=rep.verified,
             n_tasks=rep.n_tasks, n_recovered=rep.n_recovered,
-            recovery=rep.recovery, exec_time=time.perf_counter() - t0,
+            recovery=rep.recovery, exec_time=exec_time,
             plan_cached=cached, backend=backend, kernel=kern,
-            gflops=gflops)
+            phases=phases, padded_flops=padded)
 
         def finalize():
             corrected = fin()

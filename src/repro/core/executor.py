@@ -12,13 +12,14 @@ backends cannot drift.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Callable, List, Optional, Sequence, Tuple, Union
+from dataclasses import dataclass, field
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from repro.core import churn, cost_model as cm
 from repro.core.seeding import as_rng
+from repro.core.spans import span
 from repro.core.verify import freivalds
 
 
@@ -29,6 +30,10 @@ class ExecutionReport:
     n_tasks: int
     n_recovered: int
     recovery: Optional[churn.RecoveryResult]
+    # host seconds per span of this execution, keyed by the span's short
+    # name (``core.spans``): ``tasks`` and ``verify`` here, the staging,
+    # kernel, fetch and scatter phases too on the jax backend
+    phases: Dict[str, float] = field(default_factory=dict)
 
 
 @dataclass(frozen=True)
@@ -111,8 +116,10 @@ def execute_plan_deferred(
     filled = np.zeros((m, q), bool)
     corrupt = set(corrupt_ids)
     n_rec = 0
+    phases: Dict[str, float] = {}
 
-    tasks, recovery = build_task_list(gemm, plan, devices, fail_ids)
+    with span("cleave.fleet.tasks", phases):
+        tasks, recovery = build_task_list(gemm, plan, devices, fail_ids)
     for t in tasks:
         r0, r1, c0, c1 = t.r0, t.r1, t.c0, t.c1
         block = A64[r0:r1] @ B64[:, c0:c1]
@@ -126,20 +133,23 @@ def execute_plan_deferred(
     assert filled.all(), "coverage violated"
 
     report = ExecutionReport(output=C, verified=True, n_tasks=len(tasks),
-                             n_recovered=n_rec, recovery=recovery)
+                             n_recovered=n_rec, recovery=recovery,
+                             phases=phases)
 
     def finalize() -> List[TaskRect]:
         corrected: List[TaskRect] = []
         if not verify:
             return corrected
-        for t in tasks:
-            r0, r1, c0, c1 = t.r0, t.r1, t.c0, t.c1
-            Ab = A64[r0:r1]
-            Bb = B64[:, c0:c1]
-            if not freivalds(Ab, Bb, C[r0:r1, c0:c1], rng):
-                report.verified = False
-                C[r0:r1, c0:c1] = Ab @ Bb  # PS re-dispatch -> local recompute
-                corrected.append(t)
+        with span("cleave.fleet.verify", report.phases):
+            for t in tasks:
+                r0, r1, c0, c1 = t.r0, t.r1, t.c0, t.c1
+                Ab = A64[r0:r1]
+                Bb = B64[:, c0:c1]
+                if not freivalds(Ab, Bb, C[r0:r1, c0:c1], rng):
+                    report.verified = False
+                    # PS re-dispatch -> local recompute
+                    C[r0:r1, c0:c1] = Ab @ Bb
+                    corrected.append(t)
         return corrected
 
     return report, finalize

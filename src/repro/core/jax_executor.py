@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import churn, cost_model as cm
 from repro.core.executor import ExecutionReport, build_task_list
 from repro.core.seeding import as_rng
+from repro.core.spans import span
 from repro.core.verify import freivalds
 
 
@@ -89,14 +90,13 @@ def get_policy(policy: Union[str, DtypePolicy, None]) -> DtypePolicy:
 
 @dataclass
 class JaxExecutionReport(ExecutionReport):
-    """ExecutionReport plus accelerator-side throughput accounting."""
+    """ExecutionReport plus the accelerator substrate's accounting."""
     backend: str = "jax"
     kernel: str = "xla"            # 'pallas' | 'xla' (resolved)
     policy: str = "f32"
     exec_time: float = 0.0         # kernel + gather/scatter wall-clock
-    gflops: float = 0.0            # achieved GFLOP/s over exec_time
-    tasks_per_s: float = 0.0
-    verify_time: float = 0.0       # deferred Freivalds finalize wall-clock
+    padded_flops: float = 0.0      # GEMM FLOPs the bucket launches ran,
+    #                                padding included (2 bands pm nk qk)
 
 
 def _redispatch(Ab: np.ndarray, Bb: np.ndarray,
@@ -127,8 +127,8 @@ def execute_plan_jax_deferred(
     same launch) and scatters the blocks; the returned ``finalize`` closure
     reduces the residuals against the policy tolerance, confirms flagged
     blocks with the host oracle, and re-dispatches genuine corruption —
-    updating ``report.verified``/``report.verify_time`` and returning the
-    corrected rects.  Calling ``finalize()`` immediately matches
+    updating ``report.verified`` and ``report.phases["verify"]`` and
+    returning the corrected rects.  Calling ``finalize()`` immediately matches
     :func:`execute_plan_jax`; the dataflow dispatcher overlaps it with the
     next node's gathers instead (verification of node *k* behind node
     *k+1*'s staging).
@@ -163,90 +163,89 @@ def execute_plan_jax_deferred(
     m, q = gemm.m, gemm.q
     assert A.shape == (m, gemm.n) and B.shape == (gemm.n, q)
     corrupt = set(corrupt_ids)
+    phases: dict = {}
 
-    tasks, recovery = build_task_list(gemm, plan, devices, fail_ids)
-    n_rec = sum(1 for t in tasks if t.is_recovery)
+    with span("cleave.fleet.tasks", phases):
+        tasks, recovery = build_task_list(gemm, plan, devices, fail_ids)
+        n_rec = sum(1 for t in tasks if t.is_recovery)
+        rects = [(t.r0, t.r1, t.c0, t.c1) for t in tasks]
+        corrupt_mask = np.fromiter((t.device_id in corrupt for t in tasks),
+                                   np.float32, count=len(tasks))
 
     # ---- one batched (compute + verify) pass per padded-shape bucket -----
     t0 = time.perf_counter()
-    rects = [(t.r0, t.r1, t.c0, t.c1) for t in tasks]
-    corrupt_mask = np.fromiter((t.device_id in corrupt for t in tasks),
-                               np.float32, count=len(tasks))
     seed = int(rng.integers(0, 2 ** 31 - 1)) if verify else None
     runs = ops.plan_gemm_buckets(A, B, rects, block=block, kernel=kernel,
                                  compute_dtype=pol.compute_dtype,
                                  verify_seed=seed, corrupt=corrupt_mask,
-                                 pad_cache=pad_cache)
+                                 pad_cache=pad_cache, phases=phases)
 
-    C = np.zeros((m, q), np.float32)
-    filled = np.zeros((m, q), bool)
-    flops = 0.0
-    run_dims = []
-    for run in runs:
-        hs = run.band_hs.astype(np.int64)[run.bidx]
-        ws = (run.c1s - run.c0s).astype(np.int64)
-        run_dims.append((hs, ws))
-        flops += 2.0 * gemm.n * float((hs * ws).sum())
-        # vectorized scatter: each band bulk-writes the contiguous runs of
-        # its rects' column-window union (a grid partition's bands tile the
-        # width, so this is one slice write per band) instead of the old
-        # per-task Python loop
-        Gb = len(run.band_r0s)
-        cover = np.zeros((Gb, q + 1), np.int32)
-        np.add.at(cover, (run.bidx, run.c0s), 1)
-        np.add.at(cover, (run.bidx, run.c1s), -1)
-        cover = np.cumsum(cover[:, :q], axis=1) > 0
-        for b in range(Gb):
-            r0, h = int(run.band_r0s[b]), int(run.band_hs[b])
-            edges = np.flatnonzero(np.diff(cover[b].astype(np.int8)))
-            bounds = np.concatenate(
-                ([0] if cover[b, 0] else [], edges + 1,
-                 [q] if cover[b, -1] else [])).astype(np.int64)
-            for s0, s1 in bounds.reshape(-1, 2):
-                C[r0:r0 + h, s0:s1] = run.out[b, :h, s0:s1]
-                filled[r0:r0 + h, s0:s1] = True
-        if not verify:
-            # poisoning still lands in the output (nobody checks it);
-            # injected post-scatter into the writable C, same
-            # blk[0,0] += 1 + |blk[0,0]| form as the numpy executor
-            for g in np.nonzero(corrupt_mask[run.idx])[0]:
-                r0, c0 = rects[run.idx[g]][0], rects[run.idx[g]][2]
-                C[r0, c0] += 1.0 + abs(C[r0, c0])
+    with span("cleave.fleet.scatter", phases):
+        C = np.zeros((m, q), np.float32)
+        filled = np.zeros((m, q), bool)
+        for run in runs:
+            # vectorized scatter: each band bulk-writes the contiguous runs
+            # of its rects' column-window union (a grid partition's bands
+            # tile the width, so this is one slice write per band)
+            Gb = len(run.band_r0s)
+            cover = np.zeros((Gb, q + 1), np.int32)
+            np.add.at(cover, (run.bidx, run.c0s), 1)
+            np.add.at(cover, (run.bidx, run.c1s), -1)
+            cover = np.cumsum(cover[:, :q], axis=1) > 0
+            for b in range(Gb):
+                r0, h = int(run.band_r0s[b]), int(run.band_hs[b])
+                edges = np.flatnonzero(np.diff(cover[b].astype(np.int8)))
+                bounds = np.concatenate(
+                    ([0] if cover[b, 0] else [], edges + 1,
+                     [q] if cover[b, -1] else [])).astype(np.int64)
+                for s0, s1 in bounds.reshape(-1, 2):
+                    C[r0:r0 + h, s0:s1] = run.out[b, :h, s0:s1]
+                    filled[r0:r0 + h, s0:s1] = True
+            if not verify:
+                # poisoning still lands in the output (nobody checks it);
+                # injected post-scatter into the writable C, same
+                # blk[0,0] += 1 + |blk[0,0]| form as the numpy executor
+                for g in np.nonzero(corrupt_mask[run.idx])[0]:
+                    r0, c0 = rects[run.idx[g]][0], rects[run.idx[g]][2]
+                    C[r0, c0] += 1.0 + abs(C[r0, c0])
+        assert filled.all(), "coverage violated"
+        assert sum(t.area for t in tasks) == m * q, "overlapping assignment"
     exec_time = time.perf_counter() - t0
 
-    assert filled.all(), "coverage violated"
-    assert sum(t.area for t in tasks) == m * q, "overlapping assignment"
     report = JaxExecutionReport(
         output=C, verified=True, n_tasks=len(tasks), n_recovered=n_rec,
-        recovery=recovery, backend="jax", kernel=kernel, policy=pol.name,
-        exec_time=exec_time, gflops=flops / max(exec_time, 1e-12) / 1e9,
-        tasks_per_s=len(tasks) / max(exec_time, 1e-12))
+        recovery=recovery, phases=phases, backend="jax", kernel=kernel,
+        policy=pol.name, exec_time=exec_time,
+        padded_flops=sum(run.padded_flops for run in runs))
 
     def finalize() -> List[tuple]:
         corrected: List[tuple] = []
         if not verify:
             return corrected
-        t1 = time.perf_counter()
-        for run, (hs, ws) in zip(runs, run_dims):
-            rtols = pol.freivalds_c * pol.eps * np.sqrt(
-                max(gemm.n, 1) / np.maximum(hs * ws, 1))
-            ok = np.all(
-                np.abs(run.lhs - run.rhs)
-                <= rtols[:, None] * np.abs(run.rhs)
-                + (rtols * (run.scale + 1e-30))[:, None], axis=1)
-            for g in np.nonzero(~ok)[0]:
-                # device-side residual flagged this block: confirm with the
-                # host oracle, then model the PS re-dispatch to a clean
-                # device (same dtype policy) for genuine corruption
-                i = run.idx[g]
-                r0, r1, c0, c1 = rects[i]
-                if freivalds(A[r0:r1], B[:, c0:c1], run.block(g), rng,
-                             rtol=float(rtols[g])):
-                    continue
-                report.verified = False
-                C[r0:r1, c0:c1] = _redispatch(A[r0:r1], B[:, c0:c1], pol)
-                corrected.append((r0, r1, c0, c1))
-        report.verify_time += time.perf_counter() - t1
+        with span("cleave.fleet.verify", report.phases):
+            for run in runs:
+                hs = run.band_hs.astype(np.int64)[run.bidx]
+                ws = (run.c1s - run.c0s).astype(np.int64)
+                rtols = pol.freivalds_c * pol.eps * np.sqrt(
+                    max(gemm.n, 1) / np.maximum(hs * ws, 1))
+                ok = np.all(
+                    np.abs(run.lhs - run.rhs)
+                    <= rtols[:, None] * np.abs(run.rhs)
+                    + (rtols * (run.scale + 1e-30))[:, None], axis=1)
+                for g in np.nonzero(~ok)[0]:
+                    # device-side residual flagged this block: confirm with
+                    # the host oracle, then model the PS re-dispatch to a
+                    # clean device (same dtype policy) for genuine
+                    # corruption
+                    i = run.idx[g]
+                    r0, r1, c0, c1 = rects[i]
+                    if freivalds(A[r0:r1], B[:, c0:c1], run.block(g), rng,
+                                 rtol=float(rtols[g])):
+                        continue
+                    report.verified = False
+                    C[r0:r1, c0:c1] = _redispatch(A[r0:r1], B[:, c0:c1],
+                                                  pol)
+                    corrected.append((r0, r1, c0, c1))
         return corrected
 
     return report, finalize
@@ -271,8 +270,5 @@ def execute_plan_jax(gemm: cm.GEMM, plan: cm.Plan, A: np.ndarray,
         corrupt_ids=corrupt_ids, rng=rng, verify=verify, policy=policy,
         kernel=kernel, block=block, pad_cache=pad_cache)
     finalize()
-    report.exec_time += report.verify_time
-    report.gflops = (report.gflops * (report.exec_time - report.verify_time)
-                     / max(report.exec_time, 1e-12))
-    report.tasks_per_s = report.n_tasks / max(report.exec_time, 1e-12)
+    report.exec_time += report.phases.get("verify", 0.0)
     return report

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
 from repro.kernels import block_gemm as _bg
 from repro.kernels import flash_attention as _fa
 from repro.kernels import wkv6 as _wkv
@@ -265,6 +266,7 @@ class BucketRun:
     lhs: Optional[np.ndarray] = None     # (Gr, iters) Freivalds residuals
     rhs: Optional[np.ndarray] = None
     scale: Optional[np.ndarray] = None   # (Gr,) Σ|C| noise scale
+    padded_flops: float = 0.0    # 2 · bands · pm · nk · qk: what ran
 
     def block(self, g: int) -> np.ndarray:
         """Rect ``g``'s un-padded block view into its band product."""
@@ -307,15 +309,17 @@ def stage_plan_operands(a, b, rects, *, block=128,
     if not bands:
         return None, None
     pmax = max(buckets)
-    a_pad = _staged_pad(a, a.shape[0] + pmax, nk, "a", pad_cache)
-    b_pad = _staged_pad(b, nk, qk, "b", pad_cache)
+    with span("cleave.fleet.stage"):
+        a_pad = _staged_pad(a, a.shape[0] + pmax, nk, "a", pad_cache)
+        b_pad = _staged_pad(b, nk, qk, "b", pad_cache)
     return a_pad, b_pad
 
 
 def plan_gemm_buckets(a, b, rects, *, block=128, kernel="auto",
                       compute_dtype=None, verify_seed=None,
                       freivalds_iters: int = 2, corrupt=None,
-                      pad_cache: Optional[PadCache] = None):
+                      pad_cache: Optional[PadCache] = None,
+                      phases: Optional[dict] = None):
     """Bucketed execution of output rectangles of C = A @ B — the fleet
     executor's primitive.
 
@@ -333,7 +337,11 @@ def plan_gemm_buckets(a, b, rects, *, block=128, kernel="auto",
     residuals (see :func:`_bucket_gemm_verified`); ``corrupt`` is an
     optional per-rect flag vector of simulated poisoning devices.
     ``pad_cache`` reuses device-resident padded operands across calls (see
-    :class:`PadCache`).  Returns a list of :class:`BucketRun`.
+    :class:`PadCache`).  ``phases``, when given, receives the host seconds
+    of the ``tasks`` (bucket geometry), ``stage`` (fingerprint, pad and
+    upload of both operands), ``kernel`` (each launch until its outputs
+    are ready) and ``fetch`` (the outputs' copy to the host) spans.
+    Returns a list of :class:`BucketRun`.
     """
     kernel = resolve_plan_kernel(kernel)
     if compute_dtype is None:
@@ -343,53 +351,64 @@ def plan_gemm_buckets(a, b, rects, *, block=128, kernel="auto",
     b = np.asarray(b)
     m, n = a.shape
     q = b.shape[1]
-    nk, qk, bands, buckets = _bucket_geometry(a.shape, b.shape, rects, block)
+    with span("cleave.fleet.tasks", phases):
+        nk, qk, bands, buckets = _bucket_geometry(a.shape, b.shape, rects,
+                                                  block)
     runs: list = []
     if not bands:
         return runs
     # pad once: rows past the edge make every band gather legal
     pmax = max(buckets)
-    a_pad = _staged_pad(a, m + pmax, nk, "a", pad_cache)
-    b_pad = _staged_pad(b, nk, qk, "b", pad_cache)
-    key = jax.random.PRNGKey(verify_seed) if verify_seed is not None else None
+    with span("cleave.fleet.stage", phases):
+        a_pad = _staged_pad(a, m + pmax, nk, "a", pad_cache)
+        b_pad = _staged_pad(b, nk, qk, "b", pad_cache)
+        key = (jax.random.PRNGKey(verify_seed) if verify_seed is not None
+               else None)
     for pm, bucket_bands in buckets.items():
-        r0s = np.asarray([r0 for r0, _ in bucket_bands], np.int32)
-        hs = np.asarray([r1 - r0 for r0, r1 in bucket_bands], np.int32)
-        ia, bidx, slot = [], [], []
-        for bi, bk_ in enumerate(bucket_bands):
-            for si, i in enumerate(bands[bk_]):
-                ia.append(i)
-                bidx.append(bi)
-                slot.append(si)
-        ia = np.asarray(ia, np.int64)
-        bidx = np.asarray(bidx, np.int32)
-        slot = np.asarray(slot, np.int32)
-        c0s = np.asarray([rects[i][2] for i in ia], np.int32)
-        c1s = np.asarray([rects[i][3] for i in ia], np.int32)
-        bm, bn, bk = min(block, pm), min(block, qk), min(block, nk)
-        if key is None:
-            out = np.asarray(_bucket_gemm(
-                a_pad, b_pad, jnp.asarray(r0s), pm=pm, bm=bm, bn=bn, bk=bk,
-                kernel=kernel, compute_dtype=compute_dtype))
-            runs.append(BucketRun(idx=ia, pm=pm, q=q, band_r0s=r0s,
-                                  band_hs=hs, bidx=bidx, c0s=c0s, c1s=c1s,
-                                  out=out))
-        else:
-            corr = np.zeros(len(ia), np.float32) if corrupt is None \
-                else np.asarray(corrupt, np.float32)[ia]
-            R = int(max(np.bincount(bidx))) if len(bidx) else 1
-            C, lhs, rhs, scale = _bucket_gemm_verified(
-                a_pad, b_pad, jnp.asarray(r0s), jnp.asarray(hs),
-                jnp.asarray(bidx), jnp.asarray(slot), jnp.asarray(c0s),
-                jnp.asarray(c1s), jnp.asarray(corr), key,
-                jnp.asarray(ia, jnp.int32), pm=pm, R=R, bm=bm, bn=bn,
-                bk=bk, kernel=kernel, compute_dtype=compute_dtype,
-                iters=freivalds_iters)
-            runs.append(BucketRun(idx=ia, pm=pm, q=q, band_r0s=r0s,
-                                  band_hs=hs, bidx=bidx, c0s=c0s, c1s=c1s,
-                                  out=np.asarray(C), lhs=np.asarray(lhs),
-                                  rhs=np.asarray(rhs),
-                                  scale=np.asarray(scale)))
+        with span("cleave.fleet.tasks", phases):
+            r0s = np.asarray([r0 for r0, _ in bucket_bands], np.int32)
+            hs = np.asarray([r1 - r0 for r0, r1 in bucket_bands], np.int32)
+            ia, bidx, slot = [], [], []
+            for bi, bk_ in enumerate(bucket_bands):
+                for si, i in enumerate(bands[bk_]):
+                    ia.append(i)
+                    bidx.append(bi)
+                    slot.append(si)
+            ia = np.asarray(ia, np.int64)
+            bidx = np.asarray(bidx, np.int32)
+            slot = np.asarray(slot, np.int32)
+            c0s = np.asarray([rects[i][2] for i in ia], np.int32)
+            c1s = np.asarray([rects[i][3] for i in ia], np.int32)
+            bm, bn, bk = min(block, pm), min(block, qk), min(block, nk)
+            run = BucketRun(idx=ia, pm=pm, q=q, band_r0s=r0s, band_hs=hs,
+                            bidx=bidx, c0s=c0s, c1s=c1s, out=None,
+                            padded_flops=2.0 * len(r0s) * pm * nk * qk)
+            if key is not None:
+                corr = np.zeros(len(ia), np.float32) if corrupt is None \
+                    else np.asarray(corrupt, np.float32)[ia]
+                R = int(max(np.bincount(bidx))) if len(bidx) else 1
+        # the launch, then a wait on its outputs: the fetch right after
+        # would block on them anyway, so the wait adds no sync
+        with span("cleave.fleet.kernel", phases):
+            if key is None:
+                outs = (_bucket_gemm(
+                    a_pad, b_pad, jnp.asarray(r0s), pm=pm, bm=bm, bn=bn,
+                    bk=bk, kernel=kernel, compute_dtype=compute_dtype),)
+            else:
+                outs = _bucket_gemm_verified(
+                    a_pad, b_pad, jnp.asarray(r0s), jnp.asarray(hs),
+                    jnp.asarray(bidx), jnp.asarray(slot), jnp.asarray(c0s),
+                    jnp.asarray(c1s), jnp.asarray(corr), key,
+                    jnp.asarray(ia, jnp.int32), pm=pm, R=R, bm=bm, bn=bn,
+                    bk=bk, kernel=kernel, compute_dtype=compute_dtype,
+                    iters=freivalds_iters)
+            jax.block_until_ready(outs)
+        with span("cleave.fleet.fetch", phases):
+            outs = [np.asarray(x) for x in outs]
+        run.out = outs[0]
+        if key is not None:
+            run.lhs, run.rhs, run.scale = outs[1:]
+        runs.append(run)
     return runs
 
 

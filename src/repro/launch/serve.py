@@ -163,6 +163,7 @@ def main(argv=None):
               f"{srep.virtual_time / max(srep.n_tokens, 1) * 1e3:.1f}ms/tok "
               f"({srep.tokens_per_sec_priced:.1f} tok/s) | plan cache "
               f"{srep.plan_cache_hit_rate:.0%}")
+        print(f"  {srep.log_line()}")
         if args.temperature <= 0:
             fleet_toks = [r.tokens for r in sess.batcher.finished]
             mono_toks = [gen[b, :G].tolist() for b in range(B)]

@@ -42,9 +42,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.serving.batcher import ContinuousBatcher, Request
 from repro.serving.kv_cache import PagedKVCache
-from repro.train_loop.fleet_gemm import FleetGemmSession, GemmRecord
+from repro.train_loop.fleet_gemm import (FleetGemmSession, GemmRecord,
+                                         phases_line, sum_phases)
 
 
 @dataclass
@@ -63,6 +65,10 @@ class ServeStepReport:
     plan_cache_hit_rate: float
     failed_ids: Tuple[int, ...] = ()
     records: List[GemmRecord] = field(default_factory=list, repr=False)
+    # host seconds per span: the step's own (step, admit, gather,
+    # kv_upload, decode, sample, kv_write) and its fleet GEMMs' phases
+    # summed (``fleet_gemm.sum_phases``)
+    phases: Dict[str, float] = field(default_factory=dict)
 
 
 @dataclass
@@ -87,6 +93,7 @@ class ServeReport:
     n_recovered: int
     failed_ids: Tuple[int, ...] = ()
     cache: Optional[object] = None        # kv_cache.CacheStats
+    phases: Dict[str, float] = field(default_factory=dict)  # over steps
 
     def log_line(self) -> str:
         s = (f"serve: {self.n_requests} reqs {self.n_tokens} toks in "
@@ -98,7 +105,7 @@ class ServeReport:
         if self.failed_ids:
             s += (f" | failed {list(self.failed_ids)} recovered "
                   f"{self.n_recovered} tasks")
-        return s
+        return s + phases_line(self.phases)
 
 
 class ServeSession:
@@ -192,7 +199,15 @@ class ServeSession:
              fail_at_gemm: int = 0) -> Optional[ServeStepReport]:
         """One continuous-batching decode step (admit → decode one token per
         occupied slot through the fleet → scatter KV → retire).  Returns
-        ``None`` when there is nothing to decode and nothing queued."""
+        ``None`` when there is nothing to decode and nothing queued.
+        The report's ``phases["step"]`` is the span around this whole call
+        and lands on it as the call returns."""
+        phases: Dict[str, float] = {}
+        with span("cleave.serve.step", phases):
+            return self._step(phases, fail_ids, fail_at_gemm)
+
+    def _step(self, phases: Dict[str, float], fail_ids: Sequence[int],
+              fail_at_gemm: int) -> Optional[ServeStepReport]:
         import jax.numpy as jnp
 
         from repro.models import model as M
@@ -203,9 +218,10 @@ class ServeSession:
             if nxt is None:
                 return None
             self.clock = max(self.clock, nxt)
-        admitted = self.batcher.admit(self.clock, self.wall)
-        for req in admitted:
-            self._ingest(req)
+        with span("cleave.serve.admit", phases):
+            admitted = self.batcher.admit(self.clock, self.wall)
+            for req in admitted:
+                self._ingest(req)
         active = [(b, r) for b, r in enumerate(self.batcher.slots)
                   if r is not None]
         if not active:
@@ -219,16 +235,19 @@ class ServeSession:
             tokens[b, 0] = r.tokens[-1] if r.tokens else int(r.prompt[-1])
             pos[b] = r.next_pos
             rids[b] = r.rid
-        views = self.kv.gather(rids, self.cache_len)
-        cache = {nm: jnp.asarray(v) for nm, v in views.items()}
-        cache["pos"] = jnp.asarray(pos)
+        views = self.kv.gather(rids, self.cache_len, phases=phases)
+        with span("cleave.serve.kv_upload", phases):
+            cache = {nm: jnp.asarray(v) for nm, v in views.items()}
+            cache["pos"] = jnp.asarray(pos)
 
         with self.gemms.open() as fleet:
             if fail_ids:
                 fleet.arm_failure(fail_ids, at_gemm=fail_at_gemm)
-            logits, new_cache = M.decode_step(
-                self.cfg, self.params, cache, jnp.asarray(tokens),
-                scan_layers=False)
+            # its self time, less the fleet GEMMs inside it, is PS ops
+            with span("cleave.serve.decode", phases):
+                logits, new_cache = M.decode_step(
+                    self.cfg, self.params, cache, jnp.asarray(tokens),
+                    scan_layers=False)
         records, churn_reports = self.gemms.drain()
         fired = tuple(sorted({int(i) for r in records
                               for i in r.failed_ids}))
@@ -237,15 +256,17 @@ class ServeSession:
                 f"fail_at_gemm={fail_at_gemm} exceeds the step's "
                 f"{len(records)} fleet GEMMs: the failure never fired")
 
-        next_tok = np.asarray(
-            jnp.argmax(logits[:, 0, :self.cfg.vocab_size], axis=-1))
+        with span("cleave.serve.sample", phases):
+            next_tok = np.asarray(
+                jnp.argmax(logits[:, 0, :self.cfg.vocab_size], axis=-1))
         # scatter the active slots' new-token K/V back into their pages
-        act = np.asarray([b for b, _ in active])
-        act_pos = pos[act]
-        bidx, sidx = jnp.asarray(act), jnp.asarray(act_pos)
-        upd = {nm: np.asarray(new_cache[nm][:, bidx, sidx])
-               for nm in self.kv.pools}
-        self.kv.write_tokens([rids[b] for b in act], act_pos, upd)
+        with span("cleave.serve.kv_write", phases):
+            act = np.asarray([b for b, _ in active])
+            act_pos = pos[act]
+            bidx, sidx = jnp.asarray(act), jnp.asarray(act_pos)
+            upd = {nm: np.asarray(new_cache[nm][:, bidx, sidx])
+                   for nm in self.kv.pools}
+            self.kv.write_tokens([rids[b] for b in act], act_pos, upd)
         if self.check_paged_read:
             self._check_paged_read(rids)
 
@@ -258,6 +279,7 @@ class ServeSession:
             r.token_times.append(self.clock)
             r.token_walls.append(self.wall)
         retired = self.batcher.retire(self.clock, self.wall)
+        phases.update(sum_phases(records))
 
         report = ServeStepReport(
             step=self.step_index, n_active=len(active),
@@ -269,7 +291,7 @@ class ServeSession:
             verified=all(r.verified for r in records),
             plan_cache_hit_rate=(sum(r.plan_cached for r in records)
                                  / max(len(records), 1)),
-            failed_ids=fired, records=records)
+            failed_ids=fired, records=records, phases=phases)
         self.step_reports.append(report)
         self.rt.history.append({
             "event": "serve_step", "step": self.step_index,
@@ -386,6 +408,10 @@ class ServeSession:
         recs = [rec for rep in self.step_reports for rec in rep.records]
         failed = tuple(sorted({int(i) for rep in self.step_reports
                                for i in rep.failed_ids}))
+        phases: Dict[str, float] = {}
+        for rep in self.step_reports:
+            for k, v in rep.phases.items():
+                phases[k] = phases.get(k, 0.0) + v
         return ServeReport(
             n_requests=len(fin), n_tokens=n_tokens,
             n_steps=self.step_index,
@@ -401,4 +427,4 @@ class ServeSession:
             plan_cache_hit_rate=(sum(r.plan_cached for r in recs)
                                  / max(len(recs), 1)),
             n_recovered=sum(r.n_recovered for r in recs),
-            failed_ids=failed, cache=self.kv.stats())
+            failed_ids=failed, cache=self.kv.stats(), phases=phases)

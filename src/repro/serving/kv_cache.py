@@ -34,6 +34,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.core.spans import span
+
 # pools whose pages are head-major: (L, n_pages, K, page, ...)
 HEAD_MAJOR = ("k", "v", "k_scale", "v_scale")
 
@@ -210,25 +212,28 @@ class PagedKVCache:
 
     # -------------------------------------------------------------- gathers --
 
-    def gather(self, rids: Sequence[Optional[int]], cache_len: int
+    def gather(self, rids: Sequence[Optional[int]], cache_len: int,
+               phases: Optional[Dict[str, float]] = None
                ) -> Dict[str, np.ndarray]:
         """Contiguous (L, B, cache_len, ...) views for the decode step —
         one vectorized fancy-index per pool.  ``rids`` may contain ``None``
         (inactive batch slots → rows of page 0, hidden by the occupancy
-        mask)."""
-        pid = np.zeros((len(rids), cache_len), np.int64)
-        off = np.zeros((len(rids), cache_len), np.int64)
-        offs = np.arange(cache_len)
-        for b, rid in enumerate(rids):
-            if rid is None:
-                continue
-            pt = self.tables[rid]
-            cap = len(pt.pages) * self.page
-            n = min(cache_len, cap)
-            pid[b, :n], off[b, :n] = self._loc(rid, offs[:n])
-        # (L, B, cache_len, ...)
-        return {nm: self._take(nm, pool, pid, off)
-                for nm, pool in self.pools.items()}
+        mask).  ``phases`` receives the ``cleave.kv.gather`` span's
+        seconds under ``gather``."""
+        with span("cleave.kv.gather", phases):
+            pid = np.zeros((len(rids), cache_len), np.int64)
+            off = np.zeros((len(rids), cache_len), np.int64)
+            offs = np.arange(cache_len)
+            for b, rid in enumerate(rids):
+                if rid is None:
+                    continue
+                pt = self.tables[rid]
+                cap = len(pt.pages) * self.page
+                n = min(cache_len, cap)
+                pid[b, :n], off[b, :n] = self._loc(rid, offs[:n])
+            # (L, B, cache_len, ...)
+            return {nm: self._take(nm, pool, pid, off)
+                    for nm, pool in self.pools.items()}
 
     def page_table_array(self, rids: Sequence[Optional[int]]
                          ) -> "tuple[np.ndarray, np.ndarray]":

@@ -30,10 +30,11 @@ from __future__ import annotations
 import contextlib
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.train_loop import hook as _hook
 
 _SESSION: Optional["FleetGemmSession"] = None
@@ -56,12 +57,41 @@ class GemmRecord:
     plan_cached: bool
     failed_ids: Tuple[int, ...] = ()
     b: int = 4                  # element width the plan was solved for
-    verify_time: float = 0.0    # dataflow dispatch: wall of the deferred
-    #                             Freivalds check (off the critical path)
+    verify_time: float = 0.0    # wall of the Freivalds check: inside
+    #                             exec_time under level dispatch, off the
+    #                             critical path under dataflow
+    # host seconds per span of the round trip, by short name: d2h, plan,
+    # tasks, stage, kernel, fetch, scatter, verify, h2d (jax backend;
+    # the numpy executor has plan, tasks, verify)
+    phases: Dict[str, float] = field(default_factory=dict)
+    roundtrip_time: float = 0.0  # the ``cleave.fleet.gemm`` span: operands
+    #                              on the device to the output back there
+    padded_flops: float = 0.0   # GEMM FLOPs launched, padding included
+    #                             (jax backend; 0 on numpy)
 
     @property
     def flops(self) -> float:
         return 2.0 * self.m * self.n * self.q
+
+
+def sum_phases(records: Sequence[GemmRecord]) -> Dict[str, float]:
+    """The records' phases summed by name, with their round trips under
+    ``gemm``."""
+    out: Dict[str, float] = {}
+    for r in records:
+        for k, v in r.phases.items():
+            out[k] = out.get(k, 0.0) + v
+    if records:
+        out["gemm"] = sum(r.roundtrip_time for r in records)
+    return out
+
+
+def phases_line(phases: Dict[str, float]) -> str:
+    """`` | spans <name> <seconds>s ...`` for a step report's log line."""
+    if not phases:
+        return ""
+    return " | spans " + " ".join(f"{k} {v:.3f}s"
+                                  for k, v in phases.items())
 
 
 @dataclass
@@ -155,7 +185,7 @@ class FleetGemmSession:
         pending failure, so an aborted step can't leak its injection into
         the next one."""
         for record, step, fut in self._pending:
-            record.verify_time = fut.result()
+            record.verify_time = record.phases["verify"] = fut.result()
             record.verified = step.verified
             record.n_recovered = step.n_recovered
         self._pending = []
@@ -215,7 +245,11 @@ class FleetGemmSession:
             self._trace_price_memo[key] = hit
         return hit
 
-    def _execute(self, a: np.ndarray, b: np.ndarray, kind: str) -> np.ndarray:
+    def _execute(self, a: np.ndarray, b: np.ndarray, kind: str,
+                 phases: Dict[str, float]
+                 ) -> Tuple[np.ndarray, GemmRecord]:
+        """Run one GEMM on the fleet; returns its output and its record,
+        whose ``phases`` is ``phases`` with the runtime's spans added."""
         fail_ids: Tuple[int, ...] = ()
         armed = self._armed
         if armed is not None and not armed.fired \
@@ -252,13 +286,17 @@ class FleetGemmSession:
                 a, b, gemm=gemm, fail_ids=fail_ids, verify=self.verify,
                 backend=self.backend, dtype_policy=self.dtype_policy,
                 kernel=self.kernel)
+        phases.update(rep.phases)
+        with span("cleave.fleet.plan", phases):
+            predicted = self._price(rep.gemm, rep.plan)
         record = GemmRecord(
             m=rep.gemm.m, n=rep.gemm.n, q=rep.gemm.q, kind=kind,
-            exec_time=rep.exec_time,
-            predicted_makespan=self._price(rep.gemm, rep.plan),
+            exec_time=rep.exec_time, predicted_makespan=predicted,
             n_tasks=rep.n_tasks, n_recovered=rep.n_recovered,
             verified=rep.verified, plan_cached=rep.plan_cached,
-            failed_ids=fail_ids, b=gemm.b)
+            failed_ids=fail_ids, b=gemm.b,
+            verify_time=phases.get("verify", 0.0), phases=phases,
+            padded_flops=rep.padded_flops)
         if self.dispatch == "dataflow":
             # back-patch the record once its deferred check lands (drain)
             self._pending[-1] = (record, rep, self._pending[-1][2])
@@ -267,17 +305,21 @@ class FleetGemmSession:
             # the failed devices are gone for good: evict them and patch the
             # plan cache so the rest of the step plans over survivors
             self.churn_reports.append(self.rt.on_failure(fail_ids))
-        return np.ascontiguousarray(rep.output).astype(a.dtype, copy=False)
+        return rep.output, record
 
 
 # ------------------------------------------------------- custom-vjp fleet dot
 
-def _host_gemm(kind: str, a, b) -> np.ndarray:
+def _host_gemm(kind: str, a, b) -> Tuple[np.ndarray, GemmRecord]:
     sess = _SESSION
     if sess is None:
         raise RuntimeError("fleet GEMM outside an open FleetGemmSession: "
                            "open one with FleetGemmSession.open()")
-    return sess._execute(np.asarray(a), np.asarray(b), kind)
+    phases: Dict[str, float] = {}
+    # waits on whatever device op produced the operands
+    with span("cleave.fleet.d2h", phases):
+        a, b = np.asarray(a), np.asarray(b)
+    return sess._execute(a, b, kind, phases)
 
 
 def _raw_fleet_dot(a, b, kind: str):
@@ -288,7 +330,15 @@ def _raw_fleet_dot(a, b, kind: str):
         raise TypeError(
             "fleet GEMMs execute on concrete operands: run the fleet step "
             "eagerly, with no jax.jit, vmap or scan around it")
-    return jnp.asarray(_host_gemm(kind, a, b))
+    roundtrip: Dict[str, float] = {}
+    with span("cleave.fleet.gemm", roundtrip):
+        out, record = _host_gemm(kind, a, b)
+        # the upload is enqueued, not waited on: the span is its host side
+        with span("cleave.fleet.h2d", record.phases):
+            out = jnp.asarray(np.ascontiguousarray(out).astype(
+                a.dtype, copy=False))
+    record.roundtrip_time = roundtrip["gemm"]
+    return out
 
 
 def _make_fleet_dot():

@@ -16,13 +16,14 @@ between levels, exactly the paper's Table 1/2 split (<1% of step FLOPs).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.train_loop.fleet_gemm import FleetGemmSession, GemmRecord
+from repro.core.spans import span
+from repro.train_loop.fleet_gemm import (FleetGemmSession, GemmRecord,
+                                         phases_line, sum_phases)
 
 
 @dataclass
@@ -57,7 +58,11 @@ class FleetStepReport:
     # engine.price_dataflow critical path through the fleet-lowered DAG —
     # the barrier-free edge prediction (dataflow-dispatch sessions only)
     predicted_makespan_overlap: Optional[float] = None
-    fleet_verify_time: float = 0.0   # summed deferred-verify wall (dataflow)
+    fleet_verify_time: float = 0.0   # summed Freivalds verify wall (inside
+    #                                  fleet_exec_time under level dispatch)
+    # host seconds per span: the step's own (step, forward_backward, adam)
+    # and its fleet GEMMs' phases summed (``fleet_gemm.sum_phases``)
+    phases: Dict[str, float] = field(default_factory=dict)
 
     def log_line(self) -> str:
         s = (f"fleet: {self.n_gemms} gemms {self.n_tasks} tasks "
@@ -71,7 +76,7 @@ class FleetStepReport:
             s += (f" | failed {list(self.failed_ids)} "
                   f"recovered {self.n_recovered} tasks, "
                   f"{self.n_plans_patched} plans patched")
-        return s
+        return s + phases_line(self.phases)
 
 
 # DAG GEMM families the pdot hook does NOT lower onto the fleet: per-expert
@@ -273,26 +278,33 @@ class FleetTrainSession:
         from repro.optim import adam
 
         predicted, predicted_overlap = self._predict(batch)
-        t0 = time.perf_counter()
-        try:
-            with self.gemms.open() as fleet:
-                if fail_ids:
-                    fleet.arm_failure(fail_ids, at_gemm=fail_at_gemm)
+        phases: Dict[str, float] = {}
+        with span("cleave.train.step", phases):
+            try:
+                with self.gemms.open() as fleet:
+                    if fail_ids:
+                        fleet.arm_failure(fail_ids, at_gemm=fail_at_gemm)
 
-                def lf(p, b):
-                    return M.loss_fn(self.cfg, p, b, scan_layers=False,
-                                     **self.chunks)
+                    def lf(p, b):
+                        return M.loss_fn(self.cfg, p, b, scan_layers=False,
+                                         **self.chunks)
 
-                (loss, metrics), grads = jax.value_and_grad(
-                    lf, has_aux=True)(params, batch)
-                params2, opt2, opt_metrics = adam.apply(
-                    params, grads, opt_state, self.opt_cfg)
-        finally:
-            # drain unconditionally: an exception mid-step must not leak a
-            # partial step's records / armed failure / GEMM counter into
-            # the next step of this (cached, reused) session
-            records, churn_reports = self.gemms.drain()
-        wall = time.perf_counter() - t0
+                    # its self time, less the fleet GEMMs inside it, is
+                    # the PS's eager ops and autodiff
+                    with span("cleave.train.forward_backward", phases):
+                        (loss, metrics), grads = jax.value_and_grad(
+                            lf, has_aux=True)(params, batch)
+                    with span("cleave.train.adam", phases):
+                        params2, opt2, opt_metrics = adam.apply(
+                            params, grads, opt_state, self.opt_cfg)
+            finally:
+                # drain unconditionally: an exception mid-step must not
+                # leak a partial step's records / armed failure / GEMM
+                # counter into the next step of this (cached, reused)
+                # session
+                records, churn_reports = self.gemms.drain()
+        wall = phases["step"]
+        phases.update(sum_phases(records))
         # report what actually happened, not what was requested: an armed
         # failure whose at_gemm index was never reached fired nothing
         fired_ids = tuple(sorted({int(i) for r in records
@@ -325,7 +337,8 @@ class FleetTrainSession:
             n_plans_patched=n_patched, records=records,
             dispatch=self.dispatch,
             predicted_makespan_overlap=predicted_overlap,
-            fleet_verify_time=sum(r.verify_time for r in records))
+            fleet_verify_time=sum(r.verify_time for r in records),
+            phases=phases)
         # the caller's report carries the full per-GEMM trace; the
         # session-retained copy drops it so a long run doesn't grow
         # memory by ~50 records/step (the aggregates are what the log,
