@@ -108,6 +108,8 @@ class StepReport:
     phases: Dict[str, float] = field(default_factory=dict)
     padded_flops: float = 0.0   # jax backend: GEMM FLOPs launched, padding
     #                             included (``JaxExecutionReport``)
+    host_operand_bytes: int = 0  # operand bytes read on the host
+    #                              (``ExecutionReport.host_operand_bytes``)
 
 
 @dataclass
@@ -306,7 +308,11 @@ class CleaveRuntime:
         Pallas ``block_gemm`` kernel grid (``core.jax_executor``) with
         MXU-aligned padding and a bf16-compute/f32-accumulate dtype policy
         on TPU (f32/f32 elsewhere — ``interpret=True`` parity on CPU).
-        ``dtype_policy`` / ``kernel`` pass through to the jax backend."""
+        ``dtype_policy`` / ``kernel`` pass through to the jax backend,
+        which also takes device arrays (``jax.Array``): they stay on the
+        device up to the kernel launch, and the session ``PadCache``
+        serves host operands only.  ``StepReport.host_operand_bytes``
+        counts the operand bytes read on the host."""
         if gemm is None:
             gemm = cm.GEMM(m=A.shape[0], n=A.shape[1], q=B.shape[1])
         phases: Dict[str, float] = {}
@@ -362,7 +368,8 @@ class CleaveRuntime:
             n_tasks=rep.n_tasks, n_recovered=rep.n_recovered,
             recovery=rep.recovery, exec_time=exec_time,
             plan_cached=cached, backend=backend, kernel=kern,
-            phases=phases, padded_flops=padded)
+            phases=phases, padded_flops=padded,
+            host_operand_bytes=rep.host_operand_bytes)
 
     def execute_step_deferred(self, A: np.ndarray, B: np.ndarray, *,
                               gemm: Optional[cm.GEMM] = None,
@@ -452,12 +459,14 @@ class CleaveRuntime:
             n_tasks=rep.n_tasks, n_recovered=rep.n_recovered,
             recovery=rep.recovery, exec_time=exec_time,
             plan_cached=cached, backend=backend, kernel=kern,
-            phases=phases, padded_flops=padded)
+            phases=phases, padded_flops=padded,
+            host_operand_bytes=rep.host_operand_bytes)
 
         def finalize():
             corrected = fin()
             step.verified = rep.verified
             step.n_recovered = rep.n_recovered
+            step.host_operand_bytes = rep.host_operand_bytes
             return corrected
 
         return step, finalize

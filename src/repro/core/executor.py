@@ -34,6 +34,10 @@ class ExecutionReport:
     # name (``core.spans``): ``tasks`` and ``verify`` here, the staging,
     # kernel, fetch and scatter phases too on the jax backend
     phases: Dict[str, float] = field(default_factory=dict)
+    # operand bytes this execution read on the host: both operands where
+    # they came as host arrays; on the jax backend, from device operands,
+    # only the slices that verification fetched for flagged blocks
+    host_operand_bytes: int = 0
 
 
 @dataclass(frozen=True)
@@ -134,7 +138,8 @@ def execute_plan_deferred(
 
     report = ExecutionReport(output=C, verified=True, n_tasks=len(tasks),
                              n_recovered=n_rec, recovery=recovery,
-                             phases=phases)
+                             phases=phases,
+                             host_operand_bytes=A.nbytes + B.nbytes)
 
     def finalize() -> List[TaskRect]:
         corrected: List[TaskRect] = []

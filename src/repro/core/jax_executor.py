@@ -149,12 +149,22 @@ def execute_plan_jax_deferred(
     confirms the failure, a clean PS re-dispatch).  The output scatter is
     one fancy-indexed write per bucket instead of a per-task Python loop.
 
+    ``A`` and ``B`` may be host arrays or device arrays (``jax.Array``).
+    A device operand stays on the device up to the bucket launch (padded
+    there, ``kernels.ops._staged_pad``); of it, only the slices of blocks
+    that the device residuals flag are fetched to the host, for the
+    oracle and the re-dispatch.  ``report.host_operand_bytes`` counts the
+    operand bytes read on the host.
+
     ``kernel`` selects the compiled substrate
     (see :func:`repro.kernels.ops.resolve_plan_kernel`); ``policy`` the
     compute dtype; ``pad_cache`` an optional ``kernels.ops.PadCache``
-    reusing device-resident padded operands across calls.  Prefer driving
-    this through ``CleaveRuntime.execute_step(backend="jax")``.
+    reusing the device-resident padded copies of host operands across
+    calls.  Prefer driving this through
+    ``CleaveRuntime.execute_step(backend="jax")``.
     """
+    import jax
+
     from repro.kernels import ops
 
     pol = get_policy(policy)
@@ -163,6 +173,7 @@ def execute_plan_jax_deferred(
     m, q = gemm.m, gemm.q
     assert A.shape == (m, gemm.n) and B.shape == (gemm.n, q)
     corrupt = set(corrupt_ids)
+    a_dev, b_dev = isinstance(A, jax.Array), isinstance(B, jax.Array)
     phases: dict = {}
 
     with span("cleave.fleet.tasks", phases):
@@ -216,13 +227,16 @@ def execute_plan_jax_deferred(
         output=C, verified=True, n_tasks=len(tasks), n_recovered=n_rec,
         recovery=recovery, phases=phases, backend="jax", kernel=kernel,
         policy=pol.name, exec_time=exec_time,
-        padded_flops=sum(run.padded_flops for run in runs))
+        padded_flops=sum(run.padded_flops for run in runs),
+        host_operand_bytes=(0 if a_dev else A.nbytes)
+        + (0 if b_dev else B.nbytes))
 
     def finalize() -> List[tuple]:
         corrected: List[tuple] = []
         if not verify:
             return corrected
         with span("cleave.fleet.verify", report.phases):
+            flagged = []
             for run in runs:
                 hs = run.band_hs.astype(np.int64)[run.bidx]
                 ws = (run.c1s - run.c0s).astype(np.int64)
@@ -232,20 +246,34 @@ def execute_plan_jax_deferred(
                     np.abs(run.lhs - run.rhs)
                     <= rtols[:, None] * np.abs(run.rhs)
                     + (rtols * (run.scale + 1e-30))[:, None], axis=1)
-                for g in np.nonzero(~ok)[0]:
-                    # device-side residual flagged this block: confirm with
-                    # the host oracle, then model the PS re-dispatch to a
-                    # clean device (same dtype policy) for genuine
-                    # corruption
-                    i = run.idx[g]
-                    r0, r1, c0, c1 = rects[i]
-                    if freivalds(A[r0:r1], B[:, c0:c1], run.block(g), rng,
-                                 rtol=float(rtols[g])):
-                        continue
-                    report.verified = False
-                    C[r0:r1, c0:c1] = _redispatch(A[r0:r1], B[:, c0:c1],
-                                                  pol)
-                    corrected.append((r0, r1, c0, c1))
+                flagged += [(run, g, float(rtols[g]))
+                            for g in np.nonzero(~ok)[0]]
+        if not flagged:
+            return corrected
+        # the device-side residual flagged these blocks: the host oracle
+        # reads their operand slices, fetched where the operands live on
+        # the device
+        slices = []
+        for run, g, _ in flagged:
+            r0, r1, c0, c1 = rects[run.idx[g]]
+            slices.append((A[r0:r1], B[:, c0:c1]))
+        if a_dev or b_dev:
+            with span("cleave.fleet.d2h", report.phases):
+                slices = [(np.asarray(Ab), np.asarray(Bb))
+                          for Ab, Bb in slices]
+            report.host_operand_bytes += sum(
+                a_dev * Ab.nbytes + b_dev * Bb.nbytes for Ab, Bb in slices)
+        with span("cleave.fleet.verify", report.phases):
+            for (run, g, rtol), (Ab, Bb) in zip(flagged, slices):
+                # confirm with the host oracle, then model the PS
+                # re-dispatch to a clean device (same dtype policy) for
+                # genuine corruption
+                r0, r1, c0, c1 = rects[run.idx[g]]
+                if freivalds(Ab, Bb, run.block(g), rng, rtol=rtol):
+                    continue
+                report.verified = False
+                C[r0:r1, c0:c1] = _redispatch(Ab, Bb, pol)
+                corrected.append((r0, r1, c0, c1))
         return corrected
 
     return report, finalize

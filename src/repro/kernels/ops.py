@@ -59,7 +59,8 @@ def block_gemm(a, b, *, bm=128, bn=128, bk=128):
 # ------------------------------------------------------- plan execution ----
 
 class PadCache:
-    """Small keyed cache of device-resident zero-padded operands.
+    """Small keyed cache of the device-resident zero-padded copies of host
+    operands (device operands are padded in place, :func:`_staged_pad`).
 
     ``plan_gemm``'s padded ``a_pad``/``b_pad`` staging used to rebuild two
     full host copies (``np.zeros`` + fill + ``jnp.asarray``) on every call;
@@ -116,10 +117,37 @@ class PadCache:
             return val
 
 
-def _staged_pad(arr: np.ndarray, rows: int, cols: int, role: str,
-                cache: "PadCache | None"):
-    """Zero-pad ``arr`` to (rows, cols) and stage it on device, through the
-    cache when one is provided."""
+def _operand(x):
+    """A device array as it is; anything else as a host array."""
+    return x if isinstance(x, jax.Array) else np.asarray(x)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "cols", "dtype"))
+def _device_pad(x, *, rows, cols, dtype):
+    return jnp.pad(x.astype(dtype),
+                   ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+
+
+def _staged_pad(arr, rows: int, cols: int, role: str,
+                cache: "PadCache | None", compute_dtype=None):
+    """Zero-pad ``arr`` to (rows, cols) on the device.
+
+    A device array (``jax.Array``) is padded where it lives, by one jitted
+    pad per (shape, dtype, padded shape), in its own dtype or in
+    ``compute_dtype`` where that is narrower: the kernels cast to the
+    compute dtype before any arithmetic, so either gives them the bits a
+    float32 pad would.  It comes back as it is where it needs neither pad
+    nor cast, and skips the cache (a fingerprint would need the host copy
+    this path avoids).  A host array is padded in float32 on the host and
+    uploaded, through the cache when one is provided."""
+    if isinstance(arr, jax.Array):
+        dtype = jnp.dtype(compute_dtype)
+        if dtype.itemsize >= arr.dtype.itemsize:
+            dtype = arr.dtype
+        if arr.shape == (rows, cols) and arr.dtype == dtype:
+            return arr
+        return _device_pad(arr, rows=rows, cols=cols, dtype=dtype)
+
     def build():
         padded = np.zeros((rows, cols), np.float32)
         padded[:arr.shape[0], :arr.shape[1]] = arr
@@ -302,7 +330,11 @@ def stage_plan_operands(a, b, rects, *, block=128,
     would build for ``rects`` — same geometry, same cache keys — so the
     dataflow dispatcher's prefetch pool can double-buffer the next node's
     gathers against the current node's compute.  Returns
-    ``(a_pad, b_pad)`` (or ``(None, None)`` for an empty rect set)."""
+    ``(a_pad, b_pad)`` (or ``(None, None)`` for an empty rect set, or
+    where an operand is already on the device: its pad is made at the
+    launch, and nothing caches it)."""
+    if isinstance(a, jax.Array) or isinstance(b, jax.Array):
+        return None, None
     a = np.asarray(a)
     b = np.asarray(b)
     nk, qk, bands, buckets = _bucket_geometry(a.shape, b.shape, rects, block)
@@ -336,19 +368,24 @@ def plan_gemm_buckets(a, b, rects, *, block=128, kernel="auto",
     With ``verify_seed`` set, the launch also emits per-rect Freivalds
     residuals (see :func:`_bucket_gemm_verified`); ``corrupt`` is an
     optional per-rect flag vector of simulated poisoning devices.
-    ``pad_cache`` reuses device-resident padded operands across calls (see
-    :class:`PadCache`).  ``phases``, when given, receives the host seconds
-    of the ``tasks`` (bucket geometry), ``stage`` (fingerprint, pad and
-    upload of both operands), ``kernel`` (each launch until its outputs
-    are ready) and ``fetch`` (the outputs' copy to the host) spans.
-    Returns a list of :class:`BucketRun`.
+    Operands may be host arrays or device arrays (``jax.Array``); only
+    their shapes and dtypes are read on the host.  A device operand is
+    padded on the device, in its own dtype or the narrower compute dtype;
+    a host operand is padded in float32 and uploaded, reusing
+    device-resident padded operands across calls through ``pad_cache``
+    (see :class:`PadCache`, :func:`_staged_pad`).  ``phases``, when given,
+    receives the host seconds of the ``tasks`` (bucket geometry),
+    ``stage`` (the pads of both operands and the verify key's upload: for
+    a device operand, the pad's dispatch), ``kernel`` (each launch until
+    its outputs, and what they wait on, are ready) and ``fetch`` (the
+    outputs' copy to the host) spans.  Returns a list of
+    :class:`BucketRun`.
     """
     kernel = resolve_plan_kernel(kernel)
     if compute_dtype is None:
         compute_dtype = ("bfloat16" if jax.default_backend() == "tpu"
                          else "float32")
-    a = np.asarray(a)
-    b = np.asarray(b)
+    a, b = _operand(a), _operand(b)
     m, n = a.shape
     q = b.shape[1]
     with span("cleave.fleet.tasks", phases):
@@ -360,8 +397,8 @@ def plan_gemm_buckets(a, b, rects, *, block=128, kernel="auto",
     # pad once: rows past the edge make every band gather legal
     pmax = max(buckets)
     with span("cleave.fleet.stage", phases):
-        a_pad = _staged_pad(a, m + pmax, nk, "a", pad_cache)
-        b_pad = _staged_pad(b, nk, qk, "b", pad_cache)
+        a_pad = _staged_pad(a, m + pmax, nk, "a", pad_cache, compute_dtype)
+        b_pad = _staged_pad(b, nk, qk, "b", pad_cache, compute_dtype)
         key = (jax.random.PRNGKey(verify_seed) if verify_seed is not None
                else None)
     for pm, bucket_bands in buckets.items():

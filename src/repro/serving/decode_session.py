@@ -69,6 +69,7 @@ class ServeStepReport:
     # kv_upload, decode, sample, kv_write) and its fleet GEMMs' phases
     # summed (``fleet_gemm.sum_phases``)
     phases: Dict[str, float] = field(default_factory=dict)
+    host_operand_bytes: int = 0  # summed GemmRecord.host_operand_bytes
 
 
 @dataclass
@@ -94,6 +95,7 @@ class ServeReport:
     failed_ids: Tuple[int, ...] = ()
     cache: Optional[object] = None        # kv_cache.CacheStats
     phases: Dict[str, float] = field(default_factory=dict)  # over steps
+    host_operand_bytes: int = 0  # over steps
 
     def log_line(self) -> str:
         s = (f"serve: {self.n_requests} reqs {self.n_tokens} toks in "
@@ -105,7 +107,7 @@ class ServeReport:
         if self.failed_ids:
             s += (f" | failed {list(self.failed_ids)} recovered "
                   f"{self.n_recovered} tasks")
-        return s + phases_line(self.phases)
+        return s + phases_line(self.phases, self.host_operand_bytes)
 
 
 class ServeSession:
@@ -291,7 +293,8 @@ class ServeSession:
             verified=all(r.verified for r in records),
             plan_cache_hit_rate=(sum(r.plan_cached for r in records)
                                  / max(len(records), 1)),
-            failed_ids=fired, records=records, phases=phases)
+            failed_ids=fired, records=records, phases=phases,
+            host_operand_bytes=sum(r.host_operand_bytes for r in records))
         self.step_reports.append(report)
         self.rt.history.append({
             "event": "serve_step", "step": self.step_index,
@@ -427,4 +430,6 @@ class ServeSession:
             plan_cache_hit_rate=(sum(r.plan_cached for r in recs)
                                  / max(len(recs), 1)),
             n_recovered=sum(r.n_recovered for r in recs),
-            failed_ids=failed, cache=self.kv.stats(), phases=phases)
+            failed_ids=failed, cache=self.kv.stats(), phases=phases,
+            host_operand_bytes=sum(rep.host_operand_bytes
+                                   for rep in self.step_reports))

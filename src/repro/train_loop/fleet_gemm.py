@@ -11,8 +11,11 @@ both cotangents are executed by the session runtime's fleet executor:
 
 Each host call goes through :meth:`CleaveRuntime.execute_step`, i.e. the
 plan cache, the failure/recovery path (``churn.recover``), Freivalds
-verification, and — for ``backend="jax"`` — the Pallas/XLA batched kernels
-with the session ``PadCache``.
+verification, and — for ``backend="jax"`` — the Pallas/XLA batched kernels.
+The jax backend takes the operands as the device arrays they are: they are
+padded on the device and never copied to the host, save the slices of
+blocks that verification flags.  The numpy backend copies both to the host
+first.
 
 Sessions are process-global and non-nested (the one ``custom_vjp``
 primitive is shared by every caller and cannot thread ``self``), opened via
@@ -68,6 +71,9 @@ class GemmRecord:
     #                              on the device to the output back there
     padded_flops: float = 0.0   # GEMM FLOPs launched, padding included
     #                             (jax backend; 0 on numpy)
+    host_operand_bytes: int = 0  # operand bytes copied to the host: A + B
+    #                              on the numpy backend, 0 on the jax
+    #                              backend save flagged blocks' slices
 
     @property
     def flops(self) -> float:
@@ -86,12 +92,14 @@ def sum_phases(records: Sequence[GemmRecord]) -> Dict[str, float]:
     return out
 
 
-def phases_line(phases: Dict[str, float]) -> str:
-    """`` | spans <name> <seconds>s ...`` for a step report's log line."""
+def phases_line(phases: Dict[str, float], host_operand_bytes: int) -> str:
+    """`` | host operands <MB> MB | spans <name> <seconds>s ...`` for a
+    step report's log line."""
     if not phases:
         return ""
-    return " | spans " + " ".join(f"{k} {v:.3f}s"
-                                  for k, v in phases.items())
+    return (f" | host operands {host_operand_bytes / 1e6:.1f} MB"
+            " | spans " + " ".join(f"{k} {v:.3f}s"
+                                   for k, v in phases.items()))
 
 
 @dataclass
@@ -188,6 +196,7 @@ class FleetGemmSession:
             record.verify_time = record.phases["verify"] = fut.result()
             record.verified = step.verified
             record.n_recovered = step.n_recovered
+            record.host_operand_bytes = step.host_operand_bytes
         self._pending = []
         out, self.records = self.records, []
         churn, self.churn_reports = self.churn_reports, []
@@ -245,8 +254,7 @@ class FleetGemmSession:
             self._trace_price_memo[key] = hit
         return hit
 
-    def _execute(self, a: np.ndarray, b: np.ndarray, kind: str,
-                 phases: Dict[str, float]
+    def _execute(self, a, b, kind: str, phases: Dict[str, float]
                  ) -> Tuple[np.ndarray, GemmRecord]:
         """Run one GEMM on the fleet; returns its output and its record,
         whose ``phases`` is ``phases`` with the runtime's spans added."""
@@ -286,7 +294,8 @@ class FleetGemmSession:
                 a, b, gemm=gemm, fail_ids=fail_ids, verify=self.verify,
                 backend=self.backend, dtype_policy=self.dtype_policy,
                 kernel=self.kernel)
-        phases.update(rep.phases)
+        for k, v in rep.phases.items():
+            phases[k] = phases.get(k, 0.0) + v
         with span("cleave.fleet.plan", phases):
             predicted = self._price(rep.gemm, rep.plan)
         record = GemmRecord(
@@ -296,7 +305,8 @@ class FleetGemmSession:
             verified=rep.verified, plan_cached=rep.plan_cached,
             failed_ids=fail_ids, b=gemm.b,
             verify_time=phases.get("verify", 0.0), phases=phases,
-            padded_flops=rep.padded_flops)
+            padded_flops=rep.padded_flops,
+            host_operand_bytes=rep.host_operand_bytes)
         if self.dispatch == "dataflow":
             # back-patch the record once its deferred check lands (drain)
             self._pending[-1] = (record, rep, self._pending[-1][2])
@@ -316,9 +326,12 @@ def _host_gemm(kind: str, a, b) -> Tuple[np.ndarray, GemmRecord]:
         raise RuntimeError("fleet GEMM outside an open FleetGemmSession: "
                            "open one with FleetGemmSession.open()")
     phases: Dict[str, float] = {}
-    # waits on whatever device op produced the operands
+    # the numpy backend's copy waits on whatever device op produced the
+    # operands; the jax backend keeps them on the device (its kernel
+    # phase waits on their producers), so its span here is empty
     with span("cleave.fleet.d2h", phases):
-        a, b = np.asarray(a), np.asarray(b)
+        if sess.backend != "jax":
+            a, b = np.asarray(a), np.asarray(b)
     return sess._execute(a, b, kind, phases)
 
 

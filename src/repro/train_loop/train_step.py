@@ -63,6 +63,7 @@ class FleetStepReport:
     # host seconds per span: the step's own (step, forward_backward, adam)
     # and its fleet GEMMs' phases summed (``fleet_gemm.sum_phases``)
     phases: Dict[str, float] = field(default_factory=dict)
+    host_operand_bytes: int = 0  # summed GemmRecord.host_operand_bytes
 
     def log_line(self) -> str:
         s = (f"fleet: {self.n_gemms} gemms {self.n_tasks} tasks "
@@ -76,7 +77,7 @@ class FleetStepReport:
             s += (f" | failed {list(self.failed_ids)} "
                   f"recovered {self.n_recovered} tasks, "
                   f"{self.n_plans_patched} plans patched")
-        return s + phases_line(self.phases)
+        return s + phases_line(self.phases, self.host_operand_bytes)
 
 
 # DAG GEMM families the pdot hook does NOT lower onto the fleet: per-expert
@@ -338,7 +339,8 @@ class FleetTrainSession:
             dispatch=self.dispatch,
             predicted_makespan_overlap=predicted_overlap,
             fleet_verify_time=sum(r.verify_time for r in records),
-            phases=phases)
+            phases=phases,
+            host_operand_bytes=sum(r.host_operand_bytes for r in records))
         # the caller's report carries the full per-GEMM trace; the
         # session-retained copy drops it so a long run doesn't grow
         # memory by ~50 records/step (the aggregates are what the log,

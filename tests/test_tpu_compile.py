@@ -67,12 +67,11 @@ def test_block_gemm_batched_shared_bf16(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_bucket_gemm_verified_pallas(one_chip, monkeypatch):
-    """The whole verified bucket program (band gather, Pallas launch,
-    device-side Freivalds) as the jax executor launches it on TPU: 8 bands
-    of 256 rows of a (2048, 2048) x (2048, 5504) GEMM, two rectangles per
-    band.  ``_interpret`` is read while tracing, so the test steers it to
-    the compiled kernel; a fresh jit keeps an earlier CPU trace out."""
+def _compile_verified_bucket(one_chip, monkeypatch, operand_dtype):
+    """8 bands of 256 rows of a (2048, 2048) x (2048, 5504) GEMM, two
+    rectangles per band, padded operands held in ``operand_dtype``.
+    ``_interpret`` is read while tracing, so the test steers it to the
+    compiled kernel; a fresh jit keeps an earlier CPU trace out."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     Gb, R, pm = 8, 2, 256
     Gr = Gb * R
@@ -80,18 +79,37 @@ def test_bucket_gemm_verified_pallas(one_chip, monkeypatch):
                  static_argnames=("pm", "R", "bm", "bn", "bk", "kernel",
                                   "compute_dtype", "iters"))
     i32 = jnp.int32
-    args = (_sds((D_MODEL + pm, D_MODEL), jnp.float32, one_chip),
-            _sds((D_MODEL, D_FF), jnp.float32, one_chip),
+    args = (_sds((D_MODEL + pm, D_MODEL), operand_dtype, one_chip),
+            _sds((D_MODEL, D_FF), operand_dtype, one_chip),
             _sds((Gb,), i32, one_chip), _sds((Gb,), i32, one_chip),
             _sds((Gr,), i32, one_chip), _sds((Gr,), i32, one_chip),
             _sds((Gr,), i32, one_chip), _sds((Gr,), i32, one_chip),
             _sds((Gr,), jnp.float32, one_chip),
             _sds((2,), jnp.uint32, one_chip),
             _sds((Gr,), i32, one_chip))
-    text = fn.lower(*args, pm=pm, R=R, bm=128, bn=128, bk=128,
+    return fn.lower(*args, pm=pm, R=R, bm=128, bn=128, bk=128,
                     kernel="pallas", compute_dtype="bfloat16",
                     iters=2).compile().as_text()
-    assert "tpu_custom_call" in text
+
+
+def test_bucket_gemm_verified_pallas(one_chip, monkeypatch):
+    """The whole verified bucket program (band gather, Pallas launch,
+    device-side Freivalds) as the jax executor launches it on TPU from
+    host operands, padded in float32."""
+    assert "tpu_custom_call" in _compile_verified_bucket(
+        one_chip, monkeypatch, jnp.float32)
+
+
+def test_device_staged_bucket_gemm_verified_pallas(one_chip, monkeypatch):
+    """The same program from device operands, padded on the device in
+    bfloat16, and that pad itself at the OPT-13B head's width (5120 x
+    50272 to 50304 columns)."""
+    assert "tpu_custom_call" in _compile_verified_bucket(
+        one_chip, monkeypatch, jnp.bfloat16)
+    pad = ops._device_pad.lower(
+        _sds((5120, 50272), jnp.bfloat16, one_chip), rows=5120, cols=50304,
+        dtype=jnp.dtype(jnp.bfloat16)).compile()
+    assert pad.memory_analysis().output_size_in_bytes == 5120 * 50304 * 2
 
 
 def test_flash_decode_paged_serving_shapes(one_chip):
