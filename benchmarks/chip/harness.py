@@ -5,13 +5,22 @@ A cell ``<config>.<traffic>`` of ``BENCHMARK.json`` is found as
 ``configs/<config>.json``, ``traffic/<traffic>.json`` (whose ``kind`` names
 ``loops/<kind>.py``), ``cells/<cell>.json`` (the limits of its correctness
 check) and, for each per-layer metric, ``metrics/<metric>.py``.
+
+A configuration file states its whole architecture: every model key is a
+field of the program's ``ArchConfig`` (``arch``), and its ``reference``
+names ``references/<reference>.py``, the plain reference that also owns the
+weight layout (``weights``) and the FLOP counts (``flops``).  So a later
+configuration, whatever its architecture, adds files and edits none.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import importlib.util
 import json
 import os
 import sys
+import typing
 from typing import Callable, Dict, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -35,11 +44,15 @@ def find(kind: str, name: str, ext: str = ".json") -> str:
 
 
 def load_module(kind: str, name: str):
-    """``<kind>/<name>.py`` as a module (names may hold dots)."""
-    path = find(kind, name, ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"benchmarks.chip.{kind}.{name.replace('.', '_').replace('-', '_')}",
-        path)
+    """``<kind>/<name>.py`` as a module (names may hold dots), executed
+    once per file."""
+    return _exec_file(find(kind, name, ".py"), f"benchmarks.chip.{kind}."
+                      f"{name.replace('.', '_').replace('-', '_')}")
+
+
+@functools.lru_cache(maxsize=None)
+def _exec_file(path: str, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -68,20 +81,54 @@ def cell(name: str, bench: Optional[dict] = None) -> dict:
                                                           traffic["kind"])}
 
 
+# the configuration file's keys that describe it and are no model field
+DESCRIPTIVE_KEYS = frozenset({"source_part", "reference", "context_length",
+                              "optimizer", "reduced", "assumed", "deployment"})
+# the file's name for a field where the two differ
+FIELD_OF_KEY = {"head_dim": "d_head"}
+
+
+def _as_field_type(tp, value):
+    """``value`` converted to the field type ``tp`` (``Optional[t]`` as
+    ``t``; a list to a tuple)."""
+    if typing.get_origin(tp) is typing.Union:
+        if value is None:
+            return None
+        tp, = (t for t in typing.get_args(tp) if t is not type(None))
+    return tp(value)
+
+
 def arch(config: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
+    """The program's ``ArchConfig`` for a configuration file: every key
+    but the descriptive ones is a field, converted to the field's type.  A
+    key that is neither, or a file with no ``reference``, is an error."""
     from repro.configs.base import ArchConfig
-    return ArchConfig(
-        name=config["name"], family=config["family"],
-        source=config["source"], n_layers=int(config["n_layers"]),
-        d_model=int(config["d_model"]), n_heads=int(config["n_heads"]),
-        n_kv_heads=int(config["n_kv_heads"]),
-        d_head=int(config["head_dim"]), d_ff=int(config["d_ff"]),
-        vocab_size=int(config["vocab_size"]),
-        norm_eps=float(config["norm_eps"]),
-        rope_theta=float(config["rope_theta"]),
-        tie_embeddings=bool(config["tie_embeddings"]),
-        dtype=config["dtype"], param_dtype=config["param_dtype"])
+    _reference_name(config)
+    types = typing.get_type_hints(ArchConfig)
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    kw = {}
+    for key, value in config.items():
+        if key in DESCRIPTIVE_KEYS:
+            continue
+        field = FIELD_OF_KEY.get(key, key)
+        if field not in fields or field in kw:
+            raise KeyError(f"configuration {config.get('name')!r}: key "
+                           f"{key!r} is no ArchConfig field, or names one "
+                           f"twice")
+        kw[field] = _as_field_type(types[field], value)
+    return ArchConfig(**kw)
+
+
+def _reference_name(config: dict) -> str:
+    if "reference" not in config:
+        raise KeyError(f"configuration {config.get('name')!r} has no key "
+                       f"'reference' naming its plain reference module")
+    return config["reference"]
+
+
+def reference(config: dict):
+    """The configuration's plain reference, ``references/<reference>.py``."""
+    return load_module("references", _reference_name(config))
 
 
 # --------------------------------------------------------------- device --

@@ -14,11 +14,20 @@ seed.  Parameters are stored in the configuration's ``param_dtype``
 both backward mirrors) takes float8_e4m3 operands with a per-tensor scale
 and accumulates in float32 -- the step below bfloat16 that would tempt a
 faster path.
+
+The module also owns what is particular to this architecture: the weight
+layout (``leaf_specs``, which ``weights.make`` builds from the seed) and
+the operation counts (``matmul_params``, ``train_flops_per_token``,
+``decode_flops``, which ``flops`` calls for the ``mfu.*`` readers).  A
+configuration of another architecture names a reference module of its own
+that defines ``leaf_specs``, ``train_flops_per_token`` and
+``decode_flops``.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Iterable
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +36,69 @@ import numpy as np
 from benchmarks.chip.compare import leaf_norms
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# ------------------------------------------------------ layout and counts --
+
+def padded_vocab(config: dict) -> int:
+    """The embedding's rows: the vocabulary rounded up to 256, as the
+    program's initializer pads it."""
+    return 256 * math.ceil(int(config["vocab_size"]) / 256)
+
+
+def leaf_specs(config: dict) -> dict:
+    """name path -> (shape, init): ``("normal", std)`` or ``("ones",)``."""
+    L, d = int(config["n_layers"]), int(config["d_model"])
+    H, K = int(config["n_heads"]), int(config["n_kv_heads"])
+    hd, ff, V = int(config["head_dim"]), int(config["d_ff"]), padded_vocab(config)
+
+    def dense(fan_in, fan_out, stacked=True):
+        shape = (L, fan_in, fan_out) if stacked else (fan_in, fan_out)
+        return shape, ("normal", 1.0 / math.sqrt(fan_in))
+
+    specs = {
+        ("embed", "tok"): ((V, d), ("normal", 0.02)),
+        ("layers", "ln1", "scale"): ((L, d), ("ones",)),
+        ("layers", "attn", "wq"): dense(d, H * hd),
+        ("layers", "attn", "wk"): dense(d, K * hd),
+        ("layers", "attn", "wv"): dense(d, K * hd),
+        ("layers", "attn", "wo"): dense(H * hd, d),
+        ("layers", "ln2", "scale"): ((L, d), ("ones",)),
+        ("layers", "mlp", "w_gate"): dense(d, ff),
+        ("layers", "mlp", "w_up"): dense(d, ff),
+        ("layers", "mlp", "w_down"): dense(ff, d),
+        ("final_norm", "scale"): ((d,), ("ones",)),
+    }
+    if not config.get("tie_embeddings"):
+        specs[("head", "w")] = dense(d, V, stacked=False)
+    return specs
+
+
+def matmul_params(config: dict) -> int:
+    """Parameters that take part in a matmul per token: every projection
+    and the output head (over the published vocabulary)."""
+    L, d = int(config["n_layers"]), int(config["d_model"])
+    H, K = int(config["n_heads"]), int(config["n_kv_heads"])
+    hd, ff = int(config["head_dim"]), int(config["d_ff"])
+    attn = d * H * hd + 2 * d * K * hd + H * hd * d
+    mlp = 3 * d * ff
+    return L * (attn + mlp) + d * int(config["vocab_size"])
+
+
+def train_flops_per_token(config: dict, seq: int) -> float:
+    """PaLM's count (arXiv:2204.02311, appendix B): 6 N + 12 L H Q T, N the
+    matmul parameters, L layers, H heads of size Q, T the sequence."""
+    L, H, Q = (int(config["n_layers"]), int(config["n_heads"]),
+               int(config["head_dim"]))
+    return 6.0 * matmul_params(config) + 12.0 * L * H * Q * seq
+
+
+def decode_flops(config: dict, contexts: Iterable[int]) -> float:
+    """FLOPs of generating one token at each context length given: 2 N,
+    plus 4 L d ctx for attention over the token's own context."""
+    n2 = 2.0 * matmul_params(config)
+    a = 4.0 * int(config["n_layers"]) * int(config["d_model"])
+    return sum(n2 + a * c for c in contexts)
 
 
 # -------------------------------------------------------------- matmuls --

@@ -1,48 +1,21 @@
-"""Seeded weights of a dense decoder configuration, made on the device in
-one jitted call, in the type they are served in.
+"""Seeded weights of a configuration, made on the device in one jitted call,
+in the type they are served in.
 
-The tree has the layout the program's model takes (``embed.tok``, layers
-stacked on a leading axis, ``final_norm``, and ``head.w`` unless the head
-is tied to the embedding, when ``head`` is empty); a test checks it
-against the program's own initializer by shape.  The reference builds the
-same weights from the same seed, so it takes nothing the program made.
+The layout is the configuration's reference module's: its ``leaf_specs``
+maps each leaf's name path to ``(shape, init)`` or ``(shape, init,
+dtype)``, where ``init`` is ``("normal", std)``, ``("ones",)`` or
+``("zeros",)`` and a leaf that names no dtype takes the configuration's
+``param_dtype``.  The tree has the layout the program's model takes, with
+``head`` empty where no leaf lies under it (a tied head); a test checks
+each configuration's against the program's own initializer by shape and
+dtype.  The reference builds the same weights from the same seed, so it
+takes nothing the program made.
 """
 from __future__ import annotations
 
 import functools
-import math
 
-
-def padded_vocab(config: dict) -> int:
-    return 256 * math.ceil(int(config["vocab_size"]) / 256)
-
-
-def leaf_specs(config: dict) -> dict:
-    """name path -> (shape, init): ``("normal", std)`` or ``("ones",)``."""
-    L, d = int(config["n_layers"]), int(config["d_model"])
-    H, K = int(config["n_heads"]), int(config["n_kv_heads"])
-    hd, ff, V = int(config["head_dim"]), int(config["d_ff"]), padded_vocab(config)
-
-    def dense(fan_in, fan_out, stacked=True):
-        shape = (L, fan_in, fan_out) if stacked else (fan_in, fan_out)
-        return shape, ("normal", 1.0 / math.sqrt(fan_in))
-
-    specs = {
-        ("embed", "tok"): ((V, d), ("normal", 0.02)),
-        ("layers", "ln1", "scale"): ((L, d), ("ones",)),
-        ("layers", "attn", "wq"): dense(d, H * hd),
-        ("layers", "attn", "wk"): dense(d, K * hd),
-        ("layers", "attn", "wv"): dense(d, K * hd),
-        ("layers", "attn", "wo"): dense(H * hd, d),
-        ("layers", "ln2", "scale"): ((L, d), ("ones",)),
-        ("layers", "mlp", "w_gate"): dense(d, ff),
-        ("layers", "mlp", "w_up"): dense(d, ff),
-        ("layers", "mlp", "w_down"): dense(ff, d),
-        ("final_norm", "scale"): ((d,), ("ones",)),
-    }
-    if not config.get("tie_embeddings"):
-        specs[("head", "w")] = dense(d, V, stacked=False)
-    return specs
+from benchmarks.chip import harness
 
 
 def seed_key(seed: int):
@@ -71,18 +44,26 @@ def _builder(spec_items: tuple, dtype: str):
     def build(key):
         keys = jax.random.split(key, len(spec_items))
         flat = {}
-        for k, (path, (shape, init)) in zip(keys, spec_items):
+        for k, (path, spec) in zip(keys, spec_items):
+            shape, init = spec[:2]
+            dt = spec[2] if len(spec) > 2 else dtype
             if init[0] == "ones":
-                flat[path] = jnp.ones(shape, dtype)
-            else:
+                flat[path] = jnp.ones(shape, dt)
+            elif init[0] == "zeros":
+                flat[path] = jnp.zeros(shape, dt)
+            elif init[0] == "normal":
                 flat[path] = (jax.random.normal(k, shape, jnp.float32)
-                              * init[1]).astype(dtype)
+                              * init[1]).astype(dt)
+            else:
+                raise ValueError(f"leaf {path}: no init {init[0]!r}")
         return dict({"head": {}}, **_nest(flat))
 
     return jax.jit(build)
 
 
 def make(config: dict, seed: int):
-    """The configuration's weights for ``seed``, on the default device."""
-    items = tuple(sorted(leaf_specs(config).items()))
+    """The configuration's weights for ``seed``, on the default device: one
+    key a leaf, split in the order of the sorted name paths."""
+    specs = harness.reference(config).leaf_specs(config)
+    items = tuple(sorted(specs.items()))
     return _builder(items, str(config["param_dtype"]))(seed_key(seed))

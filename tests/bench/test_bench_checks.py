@@ -129,6 +129,16 @@ def test_decode_check(decode_cell, monkeypatch, fault):
     assert ok == (fault is None), readings
 
 
+def test_decode_run_reports_the_cells_metrics(decode_cell):
+    """A run's end-to-end metrics are those the cell names in
+    ``BENCHMARK.json``, each above 0."""
+    out = decode_cell["loop"].run(decode_cell, SEED, 1.0, False,
+                                  harness.CompileClock(), time.perf_counter())
+    cell = harness.cell("opt-13b-l2.decode-chat-16")
+    assert set(out["end_to_end"]) == {m["name"] for m in cell["end_to_end"]}
+    assert all(v > 0 for v in out["end_to_end"].values())
+
+
 def test_train_control_fails_the_limits(train_cell):
     """The reference with float8 GEMMs, put in the program's place."""
     ref = harness.load_module("references", "dense_decoder")
